@@ -63,7 +63,7 @@ from typing import Dict, Iterable, Iterator, List, Optional, Set, Tuple
 
 from deepflow_tpu.analysis.core import (Checker, FileContext, Finding,
                                         ProjectIndex, dotted, register)
-from deepflow_tpu.analysis.twins import resolve_ref
+from deepflow_tpu.analysis.twins import canonical_dump, resolve_ref
 
 __all__ = ["JitSite", "sites_for_path", "all_sites", "bindings_for",
            "site_fingerprint", "device_value_syncs",
@@ -389,7 +389,7 @@ def site_fingerprint(site: JitSite) -> str:
                    site.donate_argnums, site.wrapped)).encode("utf-8"))
     args = getattr(site.wrapped_def, "args", None)
     if args is not None:
-        h.update(ast.dump(args, include_attributes=False).encode("utf-8"))
+        h.update(canonical_dump(args).encode("utf-8"))
     return h.hexdigest()[:16]
 
 

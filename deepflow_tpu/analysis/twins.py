@@ -104,14 +104,30 @@ def _strip_docstrings(node: ast.AST) -> None:
             sub.body = body[1:] or [ast.Pass()]
 
 
+def canonical_dump(node) -> str:
+    """`ast.dump` without positions and without the fields whose value
+    is None or an empty list: a Python version that adds a field it
+    leaves empty (3.12's `type_params=[]` on every def and class) dumps
+    the same code to the same string."""
+    if isinstance(node, ast.AST):
+        fields = ", ".join(f"{name}={canonical_dump(value)}"
+                           for name, value in ast.iter_fields(node)
+                           if value is not None and value != [])
+        return f"{type(node).__name__}({fields})"
+    if isinstance(node, list):
+        return "[" + ", ".join(canonical_dump(x) for x in node) + "]"
+    return repr(node)
+
+
 def fingerprint(node: ast.AST) -> str:
-    """Normalized-AST hash: docstrings out, positions out — so comment
-    and layout edits don't trip the gate, while ANY executable change
-    (operator, constant, call, decorator) does."""
+    """Normalized-AST hash: docstrings out, positions out, empty fields
+    out — so comment and layout edits, and the interpreter version,
+    don't trip the gate, while ANY executable change (operator,
+    constant, call, decorator) does."""
     node = copy.deepcopy(node)
     _strip_docstrings(node)
-    dump = ast.dump(node, include_attributes=False)
-    return hashlib.sha256(dump.encode("utf-8")).hexdigest()[:16]
+    return hashlib.sha256(
+        canonical_dump(node).encode("utf-8")).hexdigest()[:16]
 
 
 # -- ref resolution ---------------------------------------------------------

@@ -1,10 +1,13 @@
 """ctypes binding for the C++ columnar decoder (native_src/decoder.cc).
 
-Compiles the shared library on first use (g++ is part of the toolchain;
-the .so is cached beside the source keyed by source mtime) and exposes
-`decode_l4_payloads`, a drop-in fast path for the flow_log decode stage.
-Falls back cleanly: `available()` is False when no compiler exists, and
-callers keep using the pure-Python decoder.
+Compiles the shared library on first use (g++ is part of the toolchain)
+and exposes `decode_l4_payloads`, a drop-in fast path for the flow_log
+decode stage. The .so's file name carries a hash of the source, the
+compiler flags and the host CPU's flags (`build_key`): `-march=native`
+code built on another machine, or from another source, is never
+loaded — it simply has another name, and this host builds its own.
+`available()` is False when no compiler exists; the flow_log pipeline
+then logs the `build_error()` and keeps the pure-Python decoder.
 
 The native ABI emits two plane blocks per batch — a [N32, capacity] u32
 block for every u32/i32 schema column and a [N64, capacity] u64 block for
@@ -15,7 +18,9 @@ batch/schema.py L4_SCHEMA order exactly.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Dict, Iterable, Optional, Tuple
@@ -25,19 +30,45 @@ import numpy as np
 from deepflow_tpu.batch.schema import L4_SCHEMA
 
 _SRC = os.path.join(os.path.dirname(__file__), "native_src", "decoder.cc")
+# -O3 -march=native -funroll-loops is load-bearing: the varint walk
+# runs ~3x faster than at generic -O2
+CXXFLAGS = ("-O3", "-march=native", "-funroll-loops", "-shared", "-fPIC",
+            "-std=c++17")
 
 
-def _so_path() -> str:
+def _cpu_flags() -> str:
+    """What -march=native compiles for: the machine and its CPU flags."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return platform.machine() + ":" + " ".join(
+                        sorted(line.split(":", 1)[1].split()))
+    except OSError:
+        pass
+    return platform.machine() + ":" + platform.processor()
+
+
+def build_key(src: str = _SRC, flags: Tuple[str, ...] = CXXFLAGS,
+              cpu: Optional[str] = None) -> str:
+    """Hash of everything the binary depends on: source bytes, compiler
+    flags and the host CPU's flags."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update(b"\0" + " ".join(flags).encode())
+    h.update(b"\0" + (_cpu_flags() if cpu is None else cpu).encode())
+    return h.hexdigest()[:16]
+
+
+def _so_path(key: Optional[str] = None) -> str:
     """Build cache location for the compiled decoder. Default: beside
     the source. `DEEPFLOW_TPU_NATIVE_DIR` overrides for read-only
     installs (the docker-compose manifest bind-mounts the repo :ro and
-    points this at a writable volume — without it the compile fails
-    silently into the pure-Python fallback)."""
-    d = os.environ.get("DEEPFLOW_TPU_NATIVE_DIR")
-    if d:
-        return os.path.join(d, "_native_decoder.so")
-    return os.path.join(os.path.dirname(__file__), "native_src",
-                        "_native_decoder.so")
+    points this at a writable volume)."""
+    d = os.environ.get("DEEPFLOW_TPU_NATIVE_DIR") or \
+        os.path.join(os.path.dirname(__file__), "native_src")
+    return os.path.join(d, f"_native_decoder-{key or build_key()}.so")
 
 
 _SO = _so_path()
@@ -54,9 +85,9 @@ _build_error: Optional[str] = None
 
 
 def _build() -> Optional[str]:
-    """Compile if stale; returns an error string or None."""
-    if os.path.exists(_SO) and \
-            os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+    """Compile unless this host's keyed binary exists; returns an error
+    string or None."""
+    if os.path.exists(_SO):
         return None
     # cache-dir creation failures degrade like every other build failure
     # (pure-Python fallback + build_error()), never a startup crash
@@ -64,10 +95,8 @@ def _build() -> Optional[str]:
         os.makedirs(os.path.dirname(_SO), exist_ok=True)
     except OSError as e:
         return f"native cache dir: {e}"
-    # -O3 -march=native -funroll-loops is load-bearing: the varint walk
-    # runs ~3x faster than at generic -O2
-    cmd = ["g++", "-O3", "-march=native", "-funroll-loops", "-shared",
-           "-fPIC", "-std=c++17", _SRC, "-o", _SO + ".tmp", "-lpthread"]
+    tmp = f"{_SO}.{os.getpid()}.tmp"
+    cmd = ["g++", *CXXFLAGS, _SRC, "-o", tmp, "-lpthread"]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=120)
@@ -75,7 +104,7 @@ def _build() -> Optional[str]:
         return str(e)
     if proc.returncode != 0:
         return proc.stderr[-2000:]
-    os.replace(_SO + ".tmp", _SO)
+    os.replace(tmp, _SO)
     return None
 
 

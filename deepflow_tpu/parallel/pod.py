@@ -1162,6 +1162,13 @@ class PodFlowSuite:
                      "last_contributed_epoch": sh.last_contributed_epoch}
                     for sh in self._shards]
 
+    def shard_devices(self) -> List[set]:
+        """The devices each shard's state leaves sit on, one set per
+        shard: a pod whose shards share a device is no pod."""
+        return [set().union(*(x.devices()
+                              for x in jax.tree_util.tree_leaves(sh.state)))
+                for sh in self._shards]
+
     def counters(self) -> dict:
         with self._ledger:
             active = sum(1 for sh in self._shards if sh.status == ACTIVE)

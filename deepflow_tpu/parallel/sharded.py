@@ -43,19 +43,6 @@ from deepflow_tpu.models.metrics_suite import (
 )
 from deepflow_tpu.ops import cms, entropy, hll, pca, topk
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# the replication-check opt-out was renamed check_rep -> check_vma;
-# detect which spelling this jax takes so both versions run
-import inspect as _inspect
-
-_CHECK_KW = ("check_vma"
-             if "check_vma" in _inspect.signature(shard_map).parameters
-             else "check_rep")
-
 
 def _replicate_init(single, n_devices: int, sharding: NamedSharding):
     """Broadcast a single-device state pytree onto the device axis."""
@@ -152,9 +139,9 @@ class _ShardedSuiteBase:
         self.audit_device_skipped = 0
 
     def _shard(self, fn, in_specs, out_specs):
-        return jax.jit(shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs,
-                                 **{_CHECK_KW: False}))
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh,
+                                     in_specs=in_specs, out_specs=out_specs,
+                                     check_vma=False))
 
     def init(self):
         return _replicate_init(self._init_single(), self.n_devices,

@@ -253,6 +253,11 @@ class FlowLogPipeline:
                 if native.available():
                     payload_fns[MessageType.TAGGEDFLOW] = \
                         native.decode_l4_payload
+                else:
+                    import logging
+                    logging.getLogger(__name__).warning(
+                        "native l4 decoder unavailable, decoding in "
+                        "Python: %s", native.build_error())
             # budget split across every consumer of the stream's writer so
             # the aggregate cap matches the config (reference: flow_log.go
             # throttle/queueCount); the l7 table is also fed by the OTel

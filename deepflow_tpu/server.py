@@ -308,6 +308,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="deepflow-tpu-server")
     ap.add_argument("-f", "--config", default=None)
     args = ap.parse_args(argv)
+    from deepflow_tpu.utils import compile_cache
+    compile_cache.configure()
     server = Server(args.config)
     server.start()
     print(f"deepflow-tpu server up: ingester :{server.ingester.port}"

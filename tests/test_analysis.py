@@ -789,6 +789,32 @@ def test_twin_ack_path_scope_merges_not_overwrites(tmp_path, capsys):
     capsys.readouterr()
 
 
+@pytest.mark.parametrize("extra", [[], None])
+def test_fingerprint_ignores_empty_fields_a_python_adds(extra):
+    """A field that a newer interpreter adds and leaves empty (3.12's
+    type_params=[] on every def) must not move the fingerprint, in
+    either direction; a filled field still must."""
+    import ast
+    import copy
+
+    from deepflow_tpu.analysis.twins import fingerprint
+
+    node = ast.parse("def f(x):\n    return x + 1\n").body[0]
+    fp = fingerprint(node)
+    grown = copy.deepcopy(node)
+    grown._fields = grown._fields + ("future_field",)
+    grown.future_field = extra
+    assert fingerprint(grown) == fp
+    if hasattr(node, "type_params"):      # the pre-3.12 shape
+        shrunk = copy.deepcopy(node)
+        shrunk._fields = tuple(f for f in shrunk._fields
+                               if f != "type_params")
+        del shrunk.type_params
+        assert fingerprint(shrunk) == fp
+    grown.future_field = [ast.Name("T", ast.Load())]
+    assert fingerprint(grown) != fp
+
+
 def test_repo_twin_store_matches_tree(repo_scan):
     """The committed .lint-twins.json is in lockstep with the shipped
     tree: the self-scan (which loads it by default) reports no drift,

@@ -12,6 +12,44 @@ pytestmark = pytest.mark.skipif(
     reason=f"native decoder unavailable: {native.build_error()}")
 
 
+def test_binary_name_is_derived_from_source_flags_and_cpu(tmp_path):
+    """The .so is named by a hash of what it depends on: a change to
+    the source, the compiler flags or the host CPU gives another name,
+    so a binary built elsewhere is never picked up."""
+    key = native.build_key()
+    assert native._SO.endswith(f"_native_decoder-{key}.so")
+    assert native.build_key(cpu=native._cpu_flags()) == key
+    other_src = tmp_path / "decoder.cc"
+    other_src.write_bytes(open(native._SRC, "rb").read() + b"\n")
+    assert native.build_key(src=str(other_src)) != key
+    assert native.build_key(flags=native.CXXFLAGS + ("-DX",)) != key
+    assert native.build_key(cpu="x86_64:sse2") != key
+    assert key in native._so_path(key)
+
+
+def test_stale_binary_from_another_build_is_not_loaded(tmp_path,
+                                                       monkeypatch):
+    """Binaries under other keys (another source or flags) and under
+    the old unkeyed name, however fresh their timestamps, are never
+    loaded: this host builds and loads its own keyed binary."""
+    monkeypatch.setenv("DEEPFLOW_TPU_NATIVE_DIR", str(tmp_path))
+    stale = [tmp_path / "_native_decoder.so",
+             tmp_path / f"_native_decoder-{native.build_key(cpu='arm')}.so",
+             tmp_path / "_native_decoder-{}.so".format(native.build_key(
+                 flags=("-O0",)))]
+    for p in stale:
+        p.write_bytes(b"not an ELF")
+    monkeypatch.setattr(native, "_SO", native._so_path())
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build_error", None)
+    assert native.available(), native.build_error()
+    assert native._SO not in {str(p) for p in stale}
+    assert all(p.read_bytes() == b"not an ELF" for p in stale)
+    got, bad = native.decode_l4_payload(
+        pack_pb_records(SyntheticAgent().l4_batch(5)[1]))
+    assert bad == 0 and len(got["ip_src"]) == 5
+
+
 def test_parity_with_python_decoder():
     agent = SyntheticAgent()
     _, records = agent.l4_batch(500)

@@ -26,6 +26,19 @@ def test_matches_xla_path(width, d, n):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("width,chunk", [
+    (1 << 17, 1024),            # the CMS width: 2048 asks 23 MB of VMEM
+    (1 << 16, 2048),
+    (1 << 12, 2048),
+    (1024 * 512, 1024),         # floor: the 1-D weight block tiles by 1024
+])
+def test_fit_chunk_stays_inside_scoped_vmem(width, chunk):
+    from deepflow_tpu.ops.mxu_hist import _split_hi_lo
+    from deepflow_tpu.ops.pallas_hist import fit_chunk
+
+    assert fit_chunk(4096, *_split_hi_lo(width)) == chunk
+
+
 def test_saturation_and_padding():
     # weights above the plane range saturate identically; n not a
     # multiple of chunk exercises the zero-weight pad rows
@@ -41,10 +54,16 @@ def test_saturation_and_padding():
 
 
 def test_method_dispatch(monkeypatch):
+    from deepflow_tpu.ops import pallas_hist
+
     idx = jnp.asarray(np.random.default_rng(0).integers(
         0, 1 << 16, (4, 9000), dtype=np.int32))
     out_x = hist(idx, 1 << 16, method="xla")
-    out_p = hist(idx, 1 << 16, method="pallas")   # interpret on CPU
+    # a forced kernel off a TPU is refused, never silently interpreted
+    with pytest.raises(ValueError, match="interpret"):
+        hist(idx, 1 << 16, method="pallas")
+    monkeypatch.setattr(pallas_hist, "INTERPRET", True)
+    out_p = hist(idx, 1 << 16, method="pallas")
     np.testing.assert_array_equal(np.asarray(out_x), np.asarray(out_p))
     # auto on CPU stays on the XLA path regardless of the env opt-in
     monkeypatch.setenv("DEEPFLOW_HIST_PALLAS", "1")

@@ -85,9 +85,9 @@ def test_sampled_admission_recall_production_path(rng):
 
 
 def test_staged_update_equals_fused():
-    """flow_suite.make_staged_update (the transfer-safe four-program
-    pipeline the tpu_sketch exporter uses on tunneled backends) produces
-    bit-identical state to the fused update."""
+    """flow_suite.make_staged_update (the four-program pipeline the
+    tpu_sketch exporter runs with staged=True) produces bit-identical
+    state to the fused update."""
     import jax
     import jax.numpy as jnp
 
