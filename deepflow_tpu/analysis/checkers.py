@@ -195,8 +195,8 @@ _DEVICE_PATH_SUFFIXES = ("runtime/tpu_sketch.py", "runtime/app_red.py",
                          # sanctioned syncs, same stance as the
                          # ISSUE 16 sampler
                          "runtime/autotune.py")
-# the sampled-drain helpers where a blocking sync is the point: explicit
-# attribution drains on every Nth batch / cold compile (PR 1), the
+# the helpers where a blocking sync is the point: the cold-compile
+# attribution drain, the
 # degraded-mode device probe (PR 2), the overlapped feed's
 # bounded-window fence — the ONE place the prefetch pipeline may block
 # on the device (ISSUE 5; feed.py _fence_one / the error-path discard) —
@@ -218,7 +218,7 @@ _DEVICE_PATH_SUFFIXES = ("runtime/tpu_sketch.py", "runtime/app_red.py",
 # (the cross-host epoch merge — the one stacked device program of the
 # DCN path). serving/cache.py's `refresh` needed no sanction at all:
 # it re-reads the bus/disk, never the device.
-_SANCTIONED_SYNCS = frozenset(["_to_device", "_timed_update", "put_batch",
+_SANCTIONED_SYNCS = frozenset(["_timed_update", "put_batch",
                                "_probe_device_locked", "_fence_one",
                                "_discard_inflight", "close_window",
                                "_compare", "device_lost", "_contribute",

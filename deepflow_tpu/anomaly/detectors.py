@@ -310,10 +310,10 @@ def make_window_step(cfg: AnomalyConfig):
     """The jitted window-step program (state donated: the anomaly chain
     is linear like the sketch chain, and the pre-step state is never a
     checkpoint payload — alerts are the durable artifact)."""
-    return jax.jit(
-        lambda s, ent, topk, card, rows: window_step(s, ent, topk, card,
-                                                     rows, cfg),
-        donate_argnums=0)
+    def anomaly_window(s, ent, topk, card, rows):
+        return window_step(s, ent, topk, card, rows, cfg)
+
+    return jax.jit(anomaly_window, donate_argnums=0)
 
 
 # -- per-wire batch-feed programs -------------------------------------------

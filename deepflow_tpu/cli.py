@@ -406,7 +406,7 @@ def cmd_trace(args) -> int:
     trace from one row id (the L7FlowTracing role). `latency`, `spans`
     and `rrt` read the ingester's flight recorder over the UDP debug
     protocol: per-stage latency quantiles, recent slow-batch spans, and
-    TPU transfer/kernel attribution."""
+    the device path's dispatch/compile stages and transfer gauges."""
     if args.action == "expand":
         if args.id is None:
             print("trace expand requires --id <l7_flow_log row _id>",
@@ -489,7 +489,7 @@ def cmd_trace(args) -> int:
                 for s in out["data"]["spans"]],
                ["AT", "STAGE", "STREAM", "BATCH", "DUR_MS", "ROWS"])
         return 0
-    # rrt: TPU transfer/kernel attribution
+    # rrt: the device path's dispatch/compile stages + transfer gauges
     out = debug_request("rrt", port=port)
     if not out.get("ok"):
         print(f"error: {out.get('error')}", file=sys.stderr)
@@ -1047,7 +1047,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "latency = per-stage p50/p95/p99 tables + "
                          "occupancy row; "
                          "spans = recent (slow) batch spans; "
-                         "rrt = TPU transfer/kernel attribution; "
+                         "rrt = device-path dispatch/compile stages "
+                         "and transfer gauges; "
                          "export = occupancy timeline as Chrome-trace/"
                          "Perfetto JSON")
     tr.add_argument("--id", type=int, default=None,

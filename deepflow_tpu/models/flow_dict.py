@@ -295,8 +295,10 @@ def make_wire_update(cfg: FlowSuiteConfig,
     small fresh scalar safe to block on after the donation."""
     sig = tuple(sig)
 
-    def prog(state: FlowSuiteState, dstate: FlowDictState,
-             flat: jnp.ndarray):
+    # one name for every signature: a device trace reads the update's
+    # programs as jit_sketch_update_dict
+    def sketch_update_dict(state: FlowSuiteState, dstate: FlowDictState,
+                           flat: jnp.ndarray):
         rows = jnp.uint32(0)
         off = len(sig)
         for i, (kind, w) in enumerate(sig):
@@ -312,7 +314,7 @@ def make_wire_update(cfg: FlowSuiteConfig,
         return state, dstate, rows
 
     import jax
-    return jax.jit(prog, donate_argnums=(0, 1))
+    return jax.jit(sketch_update_dict, donate_argnums=(0, 1))
 
 
 class FlowDictPacker:

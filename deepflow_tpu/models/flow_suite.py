@@ -252,7 +252,7 @@ def make_coalesced_update(cfg: FlowSuiteConfig, k_batches: int,
         mask = jnp.arange(plane.shape[1]) < n
         return update(state, unpack_lanes(lanes), mask, cfg)
 
-    def prog(state: FlowSuiteState, flat: jnp.ndarray):
+    def sketch_update_lanes(state: FlowSuiteState, flat: jnp.ndarray):
         slots = flat.reshape(K, slot_words(C))
         if K == 1:                     # no scan machinery for the common case
             out = _one(state, slots[0, 1:].reshape(4, C), slots[0, 0])
@@ -264,7 +264,7 @@ def make_coalesced_update(cfg: FlowSuiteConfig, k_batches: int,
         out, _ = jax.lax.scan(body, state, slots)
         return out, jnp.sum(slots[:, 0])
 
-    return jax.jit(prog, donate_argnums=0)
+    return jax.jit(sketch_update_lanes, donate_argnums=0)
 
 
 def use_fused_hists(cfg: FlowSuiteConfig) -> bool:

@@ -885,13 +885,13 @@ class HostPodCoordinator:
 
         cfg = self.cfg
 
-        def prog(stacked):
+        def pod_merge(stacked):
             merged = _sh._merge_axis0(stacked)
             merged = _sh.rescore_ring(merged)
             _fresh, out = flow_suite.flush(merged, cfg)
             return merged, out
 
-        return jax.jit(prog)
+        return jax.jit(pod_merge)
 
     def _close_epoch_collective(self, now: Optional[float]
                                 ) -> EpochResult:

@@ -131,66 +131,81 @@ class _Decoder:
             self._handle_inner(frames)
 
     def _handle_inner(self, frames: List[Frame]) -> None:
+        # child spans of `decode` (shared no-ops with tracing off): the
+        # frame parse, the enrichment join, the exporter fan-out and the
+        # throttled store write each get a self time
+        tracer = self._tracer
         self.frames += len(frames)
+        with tracer.span("decode.parse"):
+            cols = self._parse(frames)
+        if cols is None:
+            return
+        decoded = len(next(iter(cols.values()))) if cols else 0
+        self.records += decoded
+        if decoded == 0:
+            return
+        with tracer.span("decode.enrich"):
+            cols = self.enrich_fn(cols)
+        # exporters see the full (unthrottled) stream, like the reference's
+        # export() running before the CH-write throttler
+        if self.exporters is not None:
+            with tracer.span("decode.fanout"):
+                self.exporters.put(self.stream, self.index, cols)
+        if self.writer is not None:
+            with tracer.span("decode.store"):
+                if self.throttler is not None:
+                    self.throttler.offer(cols)
+                else:
+                    # unthrottled stream (diagnosis data): straight to
+                    # the writer — a reservoir sized "never drop" would
+                    # have to preallocate its whole capacity
+                    self.writer.put(cols)
+
+    def _parse(self, frames: List[Frame]
+               ) -> Optional[Dict[str, np.ndarray]]:
+        """Frames -> one chunk of schema columns, or None when nothing
+        decoded."""
         if self.frame_mode:
             try:
                 cols, bad = self.decode_fn(frames)
                 self.decode_errors += bad
             except Exception:
                 self.decode_errors += len(frames)
-                return
-            # falls through to the shared enrich/export/throttle tail
-        else:
-            # fast paths decode per frame (not one joined buffer) so a
-            # corrupt frame only loses its own tail, like the Python path;
-            # frames without a fast path pool into one record-list decode
-            parts: List[Dict[str, np.ndarray]] = []
-            records: List[bytes] = []
-            for f in frames:
-                fast = self.payload_decode_fns.get(f.msg_type)
-                if fast is not None:
-                    try:
-                        c, bad = fast(f.payload)
-                        self.decode_errors += bad
-                        if len(next(iter(c.values()))):
-                            parts.append(c)
-                        continue
-                    except Exception:
-                        pass  # fall through to the Python oracle
+                return None
+            return cols
+        # fast paths decode per frame (not one joined buffer) so a
+        # corrupt frame only loses its own tail, like the Python path;
+        # frames without a fast path pool into one record-list decode
+        parts: List[Dict[str, np.ndarray]] = []
+        records: List[bytes] = []
+        for f in frames:
+            fast = self.payload_decode_fns.get(f.msg_type)
+            if fast is not None:
                 try:
-                    records.extend(iter_pb_records(f.payload))
-                except ValueError:
-                    self.decode_errors += 1
-            if records:
-                try:
-                    c = self.decode_fn(records)
-                    self.decode_errors += len(records) - \
-                        len(next(iter(c.values())))  # bad records skipped
+                    c, bad = fast(f.payload)
+                    self.decode_errors += bad
                     if len(next(iter(c.values()))):
                         parts.append(c)
+                    continue
                 except Exception:
-                    self.decode_errors += 1
-            if not parts:
-                return
-            cols = parts[0] if len(parts) == 1 else \
-                {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
-        decoded = len(next(iter(cols.values()))) if cols else 0
-        self.records += decoded
-        if decoded == 0:
-            return
-        cols = self.enrich_fn(cols)
-        # exporters see the full (unthrottled) stream, like the reference's
-        # export() running before the CH-write throttler
-        if self.exporters is not None:
-            self.exporters.put(self.stream, self.index, cols)
-        if self.writer is not None:
-            if self.throttler is not None:
-                self.throttler.offer(cols)
-            else:
-                # unthrottled stream (diagnosis data): straight to the
-                # writer — a reservoir sized "never drop" would have to
-                # preallocate its whole capacity
-                self.writer.put(cols)
+                    pass  # fall through to the Python oracle
+            try:
+                records.extend(iter_pb_records(f.payload))
+            except ValueError:
+                self.decode_errors += 1
+        if records:
+            try:
+                c = self.decode_fn(records)
+                self.decode_errors += len(records) - \
+                    len(next(iter(c.values())))  # bad records skipped
+                if len(next(iter(c.values()))):
+                    parts.append(c)
+            except Exception:
+                self.decode_errors += 1
+        if not parts:
+            return None
+        return parts[0] if len(parts) == 1 else \
+            {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}
 
     def stop(self) -> None:
         self._halt.set()

@@ -169,10 +169,9 @@ class IngesterConfig:
     # flight recorder (runtime/tracing.py): span timing through the hot
     # path, queryable via the trace CLI / debug commands. True enables
     # the process tracer; False leaves it as-is (another ingester or a
-    # test may own it). Tracing costs ~one histogram add per batch
-    # stage; the explicit transfer/kernel drains that detailed
-    # attribution needs are SAMPLED (every 16th batch + cold
-    # compiles), so the async device pipeline keeps its shape
+    # test may own it). Tracing costs a few microseconds per batch
+    # stage and never blocks on the device (a program's first call,
+    # its compile, is the one drained call)
     trace_enabled: bool = True
     # Prometheus text-exposition listener (runtime/promexpo.py) serving
     # the Countable registry + flight-recorder histograms; None

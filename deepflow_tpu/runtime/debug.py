@@ -104,9 +104,11 @@ class DebugServer:
                              if req.get("slow_ms") is not None else None))}
 
     def _rrt(self, req: dict) -> dict:
-        """Where-time-goes attribution: TPU transfer/kernel gauges
-        (h2d MB/s, compile seconds) beside the kernel stage summaries —
-        the round-trip view of one batch through the device."""
+        """Where-time-goes attribution on the device path: the tracer
+        gauges (transfers per batch, coalesced bytes, per-program
+        compile seconds) beside the kernel stage summaries (`kernel`,
+        the feed's host-side dispatch, and `kernel.compile`). Device
+        time itself is the device trace's."""
         lat = self.tracer.latency()
         return {"enabled": self.tracer.enabled,
                 "gauges": self.tracer.gauges(),

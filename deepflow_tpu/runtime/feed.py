@@ -18,6 +18,13 @@ link):
   non-donated fence output) — the classic double-buffer window. The
   fence is also what makes staging-buffer recycling safe: a buffer
   returns to its pool only after the program that read it completed.
+- before it waits on its queue it retires, without blocking, every
+  in-flight update whose fence is already ready, and while updates stay
+  in flight it waits in short slices so it sees them retire. The
+  device track's dispatch->retire interval therefore ends where the
+  program did, not at the next batch's arrival, and a wait with nothing
+  left in flight is the device starved by the host: Tracer stage
+  `feed.starved` and the profiler's stall seconds.
 
 Accounting contract (the PR 2/PR 4 ladders depend on it):
 
@@ -59,9 +66,11 @@ __all__ = ["DeviceFeed", "InFlight"]
 _LOG = logging.getLogger(__name__)
 
 # gauge cadence: every Nth group, matching the exporter's every-16th
-# sampled-drain discipline (ISSUE 1) so enabling tracing never changes
-# the feed's shape
+# gauge cadence
 _GAUGE_EVERY = 16
+# queue-wait slice while updates are in flight: how late the feed may
+# notice that the device finished them
+_RETIRE_POLL_S = 0.001
 
 
 class InFlight(tuple):
@@ -135,9 +144,9 @@ class DeviceFeed:
         self._active_batches = 0   # group inside process_group right now
         self._tracer = default_tracer()
         # occupancy profiler (runtime/profiler.py): feed/fence/device
-        # spans at group granularity — the dispatch->fence interval is
-        # what tpu_device_busy_fraction unions, and idle q.get waits
-        # with an empty window are the feed-stall (starved device) time
+        # spans at group granularity — the dispatch->retire interval is
+        # what tpu_device_busy_fraction unions, and q.get waits with an
+        # empty window are the feed-stall (starved device) time
         self._prof = default_profiler()
         # counters (surfaced through the owner's Countable)
         self.groups = 0
@@ -211,26 +220,41 @@ class DeviceFeed:
         sup = default_supervisor()
         if self._active is not None or self._inflight:
             self._recover_after_crash()
+        # the device has had nothing in flight since this instant (None:
+        # work is in flight, or the feed is busy). It is counted when
+        # the next batch arrives: starvation preceding real work. A
+        # barrier keeps the clock running, unless no batch came since
+        # the previous control item — a window with no batch at all is
+        # an idle pipeline, not a starved device, so a pipeline without
+        # traffic accrues nothing.
+        starved_since = None
+        batch_since_ctl = False
         while True:
-            t0 = time.perf_counter()
+            self._retire_ready()
+            if self._inflight:
+                timeout = _RETIRE_POLL_S
+            else:
+                timeout = 0.2
+                if starved_since is None:
+                    starved_since = time.perf_counter()
             try:
-                item = self._q.get(timeout=0.2)
+                item = self._q.get(timeout=timeout)
             except _queue.Empty:
                 sup.beat()
                 continue
-            if not self._inflight:
-                # the device sat with an empty window until this work
-                # arrived: genuine host starvation — the gap PRECEDING
-                # real work. A pipeline that is simply idle (no traffic
-                # at all) accrues nothing: empty polls don't count, so
-                # the gauge stays a culprit signal, not an uptime clock.
-                self._prof.add_stall(time.perf_counter() - t0)
             sup.beat()
             if item[0] != "batch":
+                if not batch_since_ctl:
+                    starved_since = None
+                batch_since_ctl = False
                 if self._handle_control(item):
                     return
                 continue
+            batch_since_ctl = True
             now = time.perf_counter()
+            if starved_since is not None:
+                self._starved(now - starved_since)
+                starved_since = None
             self.queue_dwell_s += now - item[3]
             self.dwell_batches += 1
             group = [(item[1], item[2])]
@@ -248,8 +272,33 @@ class DeviceFeed:
                     ctl = nxt          # handle after the group applies
                     break
             self._apply_group(group)
-            if ctl is not None and self._handle_control(ctl):
+            if ctl is not None:
+                batch_since_ctl = False
+                if self._handle_control(ctl):
+                    return
+
+    def _starved(self, dur_s: float) -> None:
+        """The device sat with nothing in flight for `dur_s` before this
+        batch arrived. Recorded without a device-profiler annotation, so
+        that a trace names the gap by the upstream stage that starved
+        the feed."""
+        self._prof.add_stall(dur_s)
+        if self._tracer.enabled:
+            self._tracer.observe("feed.starved", dur_s)
+
+    def _retire_ready(self) -> None:
+        """Retire, oldest first and without blocking, every in-flight
+        update whose fence is ready: release its buffer, record its
+        device interval, count the fence."""
+        while self._inflight:
+            fence = self._inflight[0][0].fence
+            try:
+                ready = fence is None or fence.is_ready()
+            except Exception:
+                ready = True           # a failed fence: _fence_one says so
+            if not ready:
                 return
+            self._fence_one(*self._inflight.popleft())
 
     def _handle_control(self, item: tuple) -> bool:
         """Barrier/stop handling; True = the loop should exit."""
@@ -295,7 +344,8 @@ class DeviceFeed:
     def _fence_one(self, f: InFlight,
                    t_dispatch: Optional[float] = None) -> None:
         """Wait for one dispatched update to retire (the sanctioned
-        blocking sync of this module: the bounded-window fence). An
+        blocking sync of this module: the bounded-window fence; a no-op
+        wait when `_retire_ready` found the fence ready). An
         error here is an ASYNC device failure — the donated state chain
         behind it is poisoned, so every younger in-flight batch is
         discarded and the whole loss reported once."""
@@ -320,7 +370,7 @@ class DeviceFeed:
         if t_dispatch is not None:
             # dispatch -> retirement brackets the program's device
             # execution: the fence can only ack after completion, and
-            # the bounded window keeps retirement close behind it
+            # the retire poll keeps retirement close behind it
             self._prof.record("device", "update", t1 - t_dispatch,
                               rows=f.rows)
         if f.release is not None:

@@ -3,21 +3,19 @@
 ROADMAP item 2's acceptance bar is a *continuously measured* device-busy
 fraction, and until now that number only existed as a one-shot ratio in
 bench.py. This module keeps a bounded ring of feed/fence/dispatch/device
-spans — fed from the overlapped feed's fence points (runtime/feed.py),
-the tpu_sketch sampled drains and the sharded-mesh wrappers — and
-reduces it into live gauges:
+spans — fed from the overlapped feed's fence points (runtime/feed.py)
+and the sharded-mesh wrappers — and reduces it into live gauges:
 
 - ``tpu_device_busy_fraction``: union length of device-execution
   intervals over a sliding horizon / the horizon. On the feed path an
   interval spans dispatch -> fence retirement, which brackets the real
-  execution (the fence can only retire after the program completes, and
-  the bounded window keeps retirement close behind completion). On the
-  inline path only the every-Nth sampled attribution drains contribute,
-  so the number is authoritative with the feed on — exactly the path
-  the device-busy acceptance bar measures.
-- ``tpu_feed_stall_seconds``: cumulative seconds the feed thread sat
-  idle with NOTHING in flight — the device was starved by the host, the
-  complement of busy that names the culprit.
+  execution: the fence can only retire after the program completes,
+  and the feed retires every ready fence before it waits for work, so
+  retirement follows completion within the feed's poll slice. The
+  inline (feed-less) path records no intervals.
+- ``tpu_feed_stall_seconds``: cumulative seconds the feed thread waited
+  for work with NOTHING in flight — the device was starved by the host,
+  the complement of busy that names the culprit.
 
 The ring also exports as a Chrome-trace/Perfetto JSON timeline
 (``to_chrome_trace``) through the `trace-export` debug route and
@@ -28,8 +26,8 @@ Cost discipline mirrors runtime/tracing.py: recording is one tuple
 store per *span* (batch/group granularity, never per record), writers
 are lock-free-ish reserve-and-store under the GIL, readers snapshot
 under a lock. The profiler never blocks on the device itself — it only
-timestamps syncs that already exist (the feed fence, the sampled
-attribution drains), so enabling it cannot change the pipeline's shape.
+timestamps syncs that already exist (the feed fence), so enabling it
+cannot change the pipeline's shape.
 """
 
 from __future__ import annotations
@@ -45,14 +43,13 @@ __all__ = ["OccupancyProfiler", "default_profiler", "PROFILER_GAUGE_HELP"]
 PROFILER_GAUGE_HELP: Dict[str, str] = {
     "tpu_device_busy_fraction":
         "union of device-execution intervals over the sliding horizon "
-        "(dispatch->fence on the feed path; sampled drains inline). "
-        "ROADMAP item 2's continuously-measured device-busy number",
+        "(dispatch->fence retirement on the feed path). ROADMAP item "
+        "2's continuously-measured device-busy number",
     "tpu_feed_stall_seconds":
         "cumulative seconds the device sat with an empty in-flight "
-        "window immediately before work ARRIVED (host starvation "
-        "preceding real work, measured per arriving batch and capped "
-        "by the poll quantum; a pipeline with no traffic accrues "
-        "nothing)",
+        "window before work ARRIVED (host starvation preceding real "
+        "work, counted when the next batch arrives; a window with no "
+        "batch at all accrues nothing)",
 }
 
 # canonical track order for the trace export (tid assignment)
@@ -86,7 +83,8 @@ class OccupancyProfiler:
             dur_s, rows)
 
     def add_stall(self, dur_s: float) -> None:
-        """Feed-thread starvation time (queue empty AND window empty)."""
+        """Feed-thread starvation time (waiting for work with nothing
+        in flight)."""
         if dur_s > 0:
             self.stall_s += dur_s
 

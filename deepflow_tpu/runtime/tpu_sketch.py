@@ -351,45 +351,47 @@ class TpuSketchExporter(QueueWorkerExporter):
                 hits_batch=self._packer_hits_batch)
             self._dict_state = flow_dict.init_dict(
                 self._dict_packer.capacity)
-            self._update_hits = jax.jit(
-                lambda s, d, p, n: flow_dict.update_hits(s, d, p, n,
-                                                         self.cfg),
-                donate_argnums=0)
-            self._update_news = jax.jit(
-                lambda s, d, p, n: flow_dict.update_news(s, d, p, n,
-                                                         self.cfg),
-                donate_argnums=(0, 1))
+            # named functions: the XLA program (and a device trace)
+            # carries jit_<name>
+            def sketch_update_hits(s, d, p, n):
+                return flow_dict.update_hits(s, d, p, n, self.cfg)
+
+            def sketch_update_news(s, d, p, n):
+                return flow_dict.update_news(s, d, p, n, self.cfg)
+
+            self._update_hits = jax.jit(sketch_update_hits,
+                                        donate_argnums=0)
+            self._update_news = jax.jit(sketch_update_news,
+                                        donate_argnums=(0, 1))
         else:
             self._update = jax.jit(
                 lambda s, l, m: flow_suite.update_packed(s, l, m,
                                                          self.cfg),
                 donate_argnums=0)
         # NOT donated: the pre-flush state is also the checkpoint payload
-        self._flush_fn = jax.jit(lambda s: flow_suite.flush(s, self.cfg))
+        def sketch_flush(s):
+            return flow_suite.flush(s, self.cfg)
+
+        self._flush_fn = jax.jit(sketch_flush)
         self.rows_in = 0
         self._key_tuples: Dict[int, np.ndarray] = {}
         self.last_output: Optional[flow_suite.FlowWindowOutput] = None
         self._window_thread: Optional[threading.Thread] = None
         self._window_stop = threading.Event()
         self._state_lock = threading.Lock()
-        # flight recorder: kernel attribution (h2d / dispatch / device,
-        # first-call compile split out). _warm tracks which update
-        # programs have already compiled; h2d byte totals feed the
-        # tpu_h2d_mb_s gauge VERDICT r5 asked for. Attribution needs
-        # explicit drains to separate transfer from compute, and a
-        # drain serializes the otherwise-async device pipeline — so
-        # detailed (blocking) attribution runs on every
-        # `trace_attrib_every`-th batch plus every cold compile, and
-        # all other traced batches keep the async shape (their "kernel"
-        # span measures host-side time only). Sampling keeps the
-        # enabled-tracer overhead within the <=3% budget instead of
-        # turning observability-on into measurement-mode-always.
+        # flight recorder: the "kernel" span is the host side of a
+        # dispatch (pack, transfer, async call); device time is the
+        # device trace's (named programs, runtime/tracing.py). The one
+        # blocking attribution left is a program's first call, its
+        # compile: _warm tracks which update programs have compiled.
+        # The non-blocking transfer gauges sample every
+        # `_gauge_every`-th batch.
         self._tracer = default_tracer()
         self._warm: set = set()
         self.h2d_bytes = 0
-        self._attrib_every = 16
+        self._gauge_every = 16
         self._batches_traced = 0
-        self._detailed = False
+        self._gauge_due = False
         # -- degraded mode (fault domain: the device) ----------------------
         # On a device-classified error (XlaRuntimeError / device loss —
         # RuntimeError subclasses on every jax we run) the lane restores
@@ -573,10 +575,10 @@ class TpuSketchExporter(QueueWorkerExporter):
         Chunks arrive as (stream, idx, cols, batch_id); the batch id is
         pinned per chunk so kernel spans anchor to the decoder chunk
         that produced the rows."""
-        tracing = self._tracer.enabled
+        tr = self._tracer
         for stream, _idx, cols, *rest in chunks:
-            if tracing and rest:
-                self._tracer.set_batch(rest[0])
+            if tr.enabled and rest:
+                tr.set_batch(rest[0])
             schema_cols = self.coerce_to_schema(cols, SKETCH_L4_SCHEMA)
             if self._stager is not None or self._pod is not None:
                 # zero-copy: the sampled reverse map reads the chunk
@@ -587,53 +589,67 @@ class TpuSketchExporter(QueueWorkerExporter):
                 # The pod path samples here too: its shard workers only
                 # ever see packed lane planes.
                 self._record_key_tuples(schema_cols)
-            with self._state_lock:
-                if self._pod is not None:
-                    # pod lane: pack into the (4, B) plane and fan the
-                    # shard slices onto the per-shard queues. put_lanes
-                    # never blocks (a slow/LOST shard drops counted on
-                    # its own queue), so this is not an emission that
-                    # can deadlock — same argument as the stager put.
-                    for tb in self.batcher.put(schema_cols):  # lint: disable=emit-under-lock
-                        self._pod_submit_locked(tb)
-                elif self._stager is not None:
-                    # zero-copy: chunk columns pack straight into the
-                    # staging buffer — no TensorBatch, no batcher copy.
-                    # Not an emission: the stager is private state
-                    # guarded BY this lock (flush_window drains it under
-                    # the same lock), and its pack-pool queues drain on
-                    # workers that never take it — back-pressure, not
-                    # deadlock (the batcher.put argument).
-                    for sg in self._stager.put(schema_cols):  # lint: disable=emit-under-lock
-                        self._feed.put(  # lint: disable=emit-under-lock
-                            sg, self._tracer.current_batch()
-                            if self._tracer.enabled else -1)
-                else:
-                    # not an emission: the batcher is private state
-                    # guarded BY this lock (flush_window drains it under
-                    # the same lock); no other thread can block on it
-                    for tb in self.batcher.put(schema_cols):  # lint: disable=emit-under-lock
-                        self._submit_batch_locked(tb)
-                # counted once the chunk is fully handed to the device
-                # path (inline: on device; feed: in the bounded window,
-                # which every flush drains first), so rows_in is a
-                # processed-watermark, not an arrival count
-                self.rows_in += len(next(iter(schema_cols.values())))
-                if self._anomaly is not None:
-                    # conservation mirror: the detection lane's
-                    # rows_seen moves at the SAME boundary rows_in
-                    # does, so `anomaly.rows_seen == rows_in` is an
-                    # exact scrape-time invariant (the ci.sh anomaly
-                    # smoke asserts it through a mid-attack fault)
-                    self._anomaly.observe_rows(
-                        len(next(iter(schema_cols.values()))))
-                if self._audit is not None:
-                    # exact-shadow mirror at the SAME boundary rows_in
-                    # moves: the audit window and the sketch window see
-                    # the identical row set (flush drains batcher+feed
-                    # under this lock before closing both). Host numpy
-                    # only — the device path never sees the audit.
-                    self._audit.absorb(schema_cols)
+            # the wait for the lock is a span of its own: a window
+            # close holds it for its whole drain and publish
+            with tr.span("export.lock_wait"):
+                self._state_lock.acquire()
+            try:
+                self._absorb_chunk_locked(schema_cols)
+            finally:
+                self._state_lock.release()
+
+    def _absorb_chunk_locked(self, schema_cols) -> None:
+        """One chunk onto the device path, under _state_lock: the
+        window thread's flush_window() touches the same state under the
+        same lock."""
+        if self._pod is not None:
+            # pod lane: pack into the (4, B) plane and fan the
+            # shard slices onto the per-shard queues. put_lanes
+            # never blocks (a slow/LOST shard drops counted on
+            # its own queue), so this is not an emission that
+            # can deadlock — same argument as the stager put.
+            for tb in self.batcher.put(schema_cols):  # lint: disable=emit-under-lock
+                self._pod_submit_locked(tb)
+        elif self._stager is not None:
+            # zero-copy: chunk columns pack straight into the
+            # staging buffer — no TensorBatch, no batcher copy.
+            # Not an emission: the stager is private state
+            # guarded BY this lock (flush_window drains it under
+            # the same lock), and its pack-pool queues drain on
+            # workers that never take it — back-pressure, not
+            # deadlock (the batcher.put argument).
+            tr = self._tracer
+            for sg in self._stager.put(schema_cols):  # lint: disable=emit-under-lock
+                # blocks while the feed's bounded queue is full
+                with tr.span("export.feed_put"):
+                    self._feed.put(  # lint: disable=emit-under-lock
+                        sg, tr.current_batch() if tr.enabled else -1)
+        else:
+            # not an emission: the batcher is private state
+            # guarded BY this lock (flush_window drains it under
+            # the same lock); no other thread can block on it
+            for tb in self.batcher.put(schema_cols):  # lint: disable=emit-under-lock
+                self._submit_batch_locked(tb)
+        # counted once the chunk is fully handed to the device
+        # path (inline: on device; feed: in the bounded window,
+        # which every flush drains first), so rows_in is a
+        # processed-watermark, not an arrival count
+        self.rows_in += len(next(iter(schema_cols.values())))
+        if self._anomaly is not None:
+            # conservation mirror: the detection lane's
+            # rows_seen moves at the SAME boundary rows_in
+            # does, so `anomaly.rows_seen == rows_in` is an
+            # exact scrape-time invariant (the ci.sh anomaly
+            # smoke asserts it through a mid-attack fault)
+            self._anomaly.observe_rows(
+                len(next(iter(schema_cols.values()))))
+        if self._audit is not None:
+            # exact-shadow mirror at the SAME boundary rows_in
+            # moves: the audit window and the sketch window see
+            # the identical row set (flush drains batcher+feed
+            # under this lock before closing both). Host numpy
+            # only — the device path never sees the audit.
+            self._audit.absorb(schema_cols)
 
     def _pod_submit_locked(self, tb: TensorBatch) -> None:
         """One TensorBatch onto the pod lane: host-pack the 4-plane
@@ -657,68 +673,32 @@ class TpuSketchExporter(QueueWorkerExporter):
             tb, self._tracer.current_batch()
             if self._tracer.enabled else -1)
 
-    def _to_device(self, host_array, rows: int):
-        """jnp.asarray with flight-recorder h2d attribution. A
-        DETAILED batch adds a block_until_ready after the put — the
-        only way to separate transfer time from compute — so it is
-        sampled (see __init__); everything else stays fully async."""
-        jnp = self._jnp
-        tr = self._tracer
-        # byte/transfer counters are TRUE totals (scraped beside
-        # rows_in): every transfer counts, only the blocking
-        # measurement samples. transfers-vs-batches is the coalescing
-        # regression signal ISSUE 5 asks for — a slide back toward
-        # per-plane puts shows up as h2d_transfers outgrowing batches
+    def _to_device(self, host_array):
+        """jnp.asarray, counted. The byte/transfer counters are TRUE
+        totals (scraped beside rows_in): transfers-vs-batches is the
+        coalescing regression signal — a slide back toward per-plane
+        puts shows up as h2d_transfers outgrowing batches."""
         self.h2d_bytes += host_array.nbytes
         self.h2d_transfers += 1
-        if not (tr.enabled and self._detailed):
-            return jnp.asarray(host_array)
-        t0 = time.perf_counter()
-        dev = jnp.asarray(host_array)
-        dev.block_until_ready()
-        dt = time.perf_counter() - t0
-        tr.observe("kernel.h2d", dt, stream=self.wire, rows=rows)
-        self._prof.record("h2d", self.wire, dt, rows=rows)
-        if dt > 0:
-            tr.gauge("tpu_h2d_mb_s", host_array.nbytes / 1e6 / dt)
-        return dev
+        return self._jnp.asarray(host_array)
 
     def _timed_update(self, key: str, fn, *args):
-        """Dispatch + drain attribution around one jitted update call.
-        The first call per program is COMPILE (recorded as its own
-        stage and gauge, never polluting the steady-state kernel
-        quantiles); later calls split into dispatch (host returns) and
-        device (block_until_ready drain). Runs the plain async call
-        unless this batch is a sampled detailed one or the program is
-        cold (a compile must always be attributed — missing it would
-        poison the first sampled batch's device quantile instead)."""
+        """One jitted update call. The first call per program is its
+        COMPILE: with tracing on it is drained and recorded as stage
+        `kernel.compile` and gauge `tpu_compile_s_<key>`, so an
+        operator sees which program recompiled. Every later call is the
+        plain async call: its device time is the device trace's."""
         tr = self._tracer
         self.dispatches += 1
-        first = key not in self._warm
-        if not tr.enabled or not (self._detailed or first):
+        if not tr.enabled or key in self._warm:
             return fn(*args)
         import jax
         t0 = time.perf_counter()
-        out = fn(*args)
-        t1 = time.perf_counter()
-        jax.block_until_ready(out)
-        t2 = time.perf_counter()
-        if first:
-            self._warm.add(key)
-            tr.observe("kernel.compile", t2 - t0, stream=key)
-            tr.gauge(f"tpu_compile_s_{key}", t2 - t0)
-            self._prof.record("device", f"compile:{key}", t2 - t0)
-        else:
-            tr.observe("kernel.dispatch", t1 - t0, stream=key)
-            tr.observe("kernel.device", t2 - t1, stream=key)
-            # sampled occupancy evidence for the inline path (the feed
-            # path's fence intervals are the continuous signal). The
-            # dispatch span ENDED a device-execution ago — anchor its
-            # wall-clock end back so the exported timeline shows
-            # dispatch preceding device, not stacked on top of it.
-            self._prof.record("dispatch", key, t1 - t0,
-                              t_end=time.time() - (t2 - t1))
-            self._prof.record("device", key, t2 - t1)
+        out = jax.block_until_ready(fn(*args))
+        dt = time.perf_counter() - t0
+        self._warm.add(key)
+        tr.observe("kernel.compile", dt, stream=key)
+        tr.gauge(f"tpu_compile_s_{key}", dt)
         return out
 
     def _run_batch_locked(self, tb: TensorBatch) -> None:
@@ -733,7 +713,7 @@ class TpuSketchExporter(QueueWorkerExporter):
             before = self.h2d_transfers
             with tr.span("kernel", stream=self.wire, rows=tb.valid):
                 self._run_batch_inner_locked(tb)
-            if self._detailed:
+            if self._gauge_due:
                 # the same coalescing-regression gauge the feed path
                 # records: the inline path honestly reads its
                 # per-plane/per-column transfer count (> 1)
@@ -844,12 +824,7 @@ class TpuSketchExporter(QueueWorkerExporter):
         return True
 
     def _run_batch_inner_locked(self, tb: TensorBatch) -> None:
-        if self._faults.enabled:   # chaos: simulated device loss
-            self._faults.maybe_raise(FAULT_DEVICE_ERROR, key=self.wire)
-        if self._tracer.enabled:
-            self._detailed = \
-                self._batches_traced % self._attrib_every == 0
-            self._batches_traced += 1
+        self._dispatch_begin()
         self._record_key_tuples(tb.columns)
         if self._dict_packer is not None:
             # dictionary lane: pack only the VALID rows (the packer's
@@ -860,7 +835,7 @@ class TpuSketchExporter(QueueWorkerExporter):
             wire = self._dict_packer.pack(cols) + self._dict_packer.flush()
             for kind, plane, n in wire:
                 nn = np.uint32(n)
-                plane_d = self._to_device(plane, n)
+                plane_d = self._to_device(plane)
                 if kind == "news":
                     self.state, self._dict_state = self._timed_update(
                         "news", self._update_news,
@@ -875,10 +850,9 @@ class TpuSketchExporter(QueueWorkerExporter):
                         self._anomaly.feed_hits(
                             self._dict_state.table, plane_d, nn)
             return
-        n = tb.valid
-        mask_d = self._to_device(tb.mask(), n)
+        mask_d = self._to_device(tb.mask())
         if self.staged:   # staged update consumes the full column dict
-            cols_d = {k: self._to_device(v, n)
+            cols_d = {k: self._to_device(v)
                       for k, v in tb.columns.items()}
             self.state = self._timed_update(
                 "staged", self._update, self.state, cols_d, mask_d)
@@ -886,7 +860,7 @@ class TpuSketchExporter(QueueWorkerExporter):
                 self._anomaly.feed_cols(cols_d, mask_d)
             return
         lanes = flow_suite.pack_lanes(tb.columns)
-        lanes_d = {k: self._to_device(v, n) for k, v in lanes.items()}
+        lanes_d = {k: self._to_device(v) for k, v in lanes.items()}
         self.state = self._timed_update(
             "packed", self._update, self.state, lanes_d, mask_d)
         if self._anomaly is not None:
@@ -943,14 +917,15 @@ class TpuSketchExporter(QueueWorkerExporter):
         self.batcher.recycle(tb)
 
     def _dispatch_begin(self) -> int:
-        """Chaos fault injection + the every-Nth detailed-attribution
-        cadence shared by both dispatch twins; returns the h2d
-        transfer count before the dispatch for the per-batch gauge."""
+        """Chaos fault injection + the every-Nth transfer-gauge cadence
+        shared by the inline path and the dispatch twins; returns the
+        h2d transfer count before the dispatch for the per-batch
+        gauge."""
         if self._faults.enabled:   # chaos: simulated device loss
             self._faults.maybe_raise(FAULT_DEVICE_ERROR, key=self.wire)
         if self._tracer.enabled:
-            self._detailed = \
-                self._batches_traced % self._attrib_every == 0
+            self._gauge_due = \
+                self._batches_traced % self._gauge_every == 0
             self._batches_traced += 1
         return self.h2d_transfers
 
@@ -963,7 +938,7 @@ class TpuSketchExporter(QueueWorkerExporter):
             staged = self._dispatch_dict_group(group)
         else:
             staged = self._dispatch_lanes_group(group)
-        if tr.enabled and self._detailed:
+        if tr.enabled and self._gauge_due:
             tr.gauge("tpu_transfers_per_batch",
                      (self.h2d_transfers - before) / len(group))
         if staged is None:
@@ -972,7 +947,7 @@ class TpuSketchExporter(QueueWorkerExporter):
             # data was abandoned — nothing for the ledger to count
             return None  # lint: disable=silent-drop
         fence, flat = staged
-        if tr.enabled and self._detailed:
+        if tr.enabled and self._gauge_due:
             tr.gauge("tpu_h2d_coalesced_bytes", float(flat.nbytes))
         return InFlight(fence, rows,
                         lambda: self._staging_release(flat))
@@ -992,8 +967,7 @@ class TpuSketchExporter(QueueWorkerExporter):
         prog = self._program(
             ("lanes", K, C),
             lambda: flow_suite.make_coalesced_update(self.cfg, K, C))
-        flat_d = self._to_device(flat, sum(int(tb.valid)
-                                          for tb, _ in group))
+        flat_d = self._to_device(flat)
         self.state, fence = self._timed_update(
             f"lanes_x{K}", prog, self.state, flat_d)
         if self._anomaly is not None:
@@ -1022,7 +996,7 @@ class TpuSketchExporter(QueueWorkerExporter):
         fd.stage_wire(wire, flat)
         prog = self._program(
             ("dict", sig), lambda: fd.make_wire_update(self.cfg, sig))
-        flat_d = self._to_device(flat, sum(n for _, _, n in wire))
+        flat_d = self._to_device(flat)
         key = "dict:" + "+".join(f"{k[0]}{w}" for k, w in sig)
         self.state, self._dict_state, fence = self._timed_update(
             key, prog, self.state, self._dict_state, flat_d)
@@ -1058,12 +1032,12 @@ class TpuSketchExporter(QueueWorkerExporter):
                 ("lanes", sg.k, sg.capacity),
                 lambda k=sg.k, c=sg.capacity:
                 flow_suite.make_coalesced_update(self.cfg, k, c))
-            flat_d = self._to_device(sg.flat, sg.valid)
+            flat_d = self._to_device(sg.flat)
             self.state, fence = self._timed_update(
                 f"lanes_x{sg.k}", prog, self.state, flat_d)
             if self._anomaly is not None:
                 self._anomaly.feed_flat(flat_d, sg.k, sg.capacity)
-        if tr.enabled and self._detailed:
+        if tr.enabled and self._gauge_due:
             tr.gauge("tpu_transfers_per_batch",
                      (self.h2d_transfers - before)
                      / max(1, sum(sg.k for sg, _ in group)))
@@ -1129,7 +1103,7 @@ class TpuSketchExporter(QueueWorkerExporter):
             prog = self._program(
                 ("dict", sg.sig),
                 lambda s=sg.sig: fd.make_wire_update(self.cfg, s))
-            flat_d = self._to_device(sg.flat, sg.valid)
+            flat_d = self._to_device(sg.flat)
             key = "dict:" + "+".join(f"{k[0]}{w}" for k, w in sg.sig)
             self.state, self._dict_state, fence = self._timed_update(
                 key, prog, self.state, self._dict_state, flat_d)
@@ -1137,7 +1111,7 @@ class TpuSketchExporter(QueueWorkerExporter):
                 self._anomaly.feed_dict_flat(self._dict_state.table,
                                              flat_d, sg.sig)
             live.append(sg)
-        if tr.enabled and self._detailed:
+        if tr.enabled and self._gauge_due:
             tr.gauge("tpu_transfers_per_batch",
                      (self.h2d_transfers - before)
                      / max(1, sum(sg.k for sg, _ in group)))
@@ -1360,95 +1334,13 @@ class TpuSketchExporter(QueueWorkerExporter):
             self.last_output = out
             self._write_output(out, int(now))
             return out
-        with self._state_lock:
-            if self._stager is not None:
-                # zero-copy: the open staging prefix ships as-is (slot
-                # contiguity — no repack); same put-under-lock shape as
-                # _submit_batch_locked, same back-pressure-not-deadlock
-                # argument
-                for sg in self._stager.flush():
-                    self._feed.put(sg, -1)  # lint: disable=emit-under-lock
-            else:
-                for tb in self.batcher.flush():
-                    self._submit_batch_locked(tb)
-            if self._feed is not None:
-                # barrier: every in-flight prefetched batch applies and
-                # fences before the window reads/resets state (feed.py
-                # ownership protocol). The feed thread never takes
-                # _state_lock, so holding it across the wait is safe.
-                if not self._feed.drain(timeout=60.0):
-                    import logging
-                    logging.getLogger(__name__).error(
-                        "feed drain timed out; window flushed against "
-                        "a possibly-advancing state")
-            self.windows += 1
-            was_degraded = self.degraded
-            if self.degraded:
-                # host fallback window: reduced-fidelity output, then
-                # probe the device for recovery
-                out = None if self._host is None \
-                    else self._host.flush(self.cfg)
-                self._rows_at_flush = self.rows_in
-                self._probe_device_locked()
-            else:
-                # checkpoint the PRE-flush state (the window's
-                # accumulation): restore replays the window
-                # at-least-once; saving post-flush would snapshot a
-                # reset state and recover nothing. Cadence: every
-                # checkpoint_every-th window, and only if THIS window's
-                # accumulation is non-empty (a full npz per idle 1s
-                # window is not "low-overhead"). Rows in already-flushed
-                # windows need no snapshot — their output reached the
-                # store; restart loses at most the current accumulation,
-                # bounded by checkpoint_every windows of data.
-                dirty = self.rows_in != self._rows_at_flush
-                # snapshot bus (ISSUE 7): a disk publish on the PR 4
-                # cadence, PLUS a subscriber-only (no npz) publish for
-                # every dirty window when the serving cache is listening
-                # — its staleness bound is one window, not
-                # checkpoint_every windows. No subscribers, no cadence
-                # hit => no device_get at all (the pre-ISSUE 7 shape).
-                want_disk = (self.checkpointer is not None and dirty
-                             and self.windows % self.checkpoint_every == 0)
-                if want_disk or (dirty and self._snapbus.has_subscribers()):
-                    self._snapbus.publish(
-                        self.state, self.windows, wall_time=now,
-                        tags={"lossy": self._window_lost_counted},
-                        to_disk=want_disk)
-                self._rows_at_flush = self.rows_in
-                try:
-                    self.state, out = self._flush_fn(self.state)
-                except RuntimeError:
-                    # the window readback itself died on device: same
-                    # classification + recovery as a batch failure
-                    self._on_device_error_locked(0)
-                    out = None
-            if self._anomaly is not None:
-                # anomaly plane (ISSUE 15): score the settled window
-                # BEFORE the audit closes so the detection audit can
-                # compare the device verdict against the exact shadow's
-                # twin scorer. Publication happens after the lock
-                # releases (publish_pending below) — bus subscribers
-                # and the exporter fan-out are emissions.
-                self._anomaly.close_window(
-                    out, now=now, lossy=self._window_lost_counted,
-                    degraded=was_degraded)
-            if self._audit is not None:
-                # accuracy observatory: compare the settled window
-                # against the exact shadow AT the window boundary (same
-                # lock, after the drain barrier — the shadow and the
-                # sketch saw the identical row set). A window with
-                # counted loss or on the degraded lane is audited too,
-                # tagged instead of alarmed on.
-                self._audit.close_window(
-                    out, degraded=was_degraded,
-                    lossy=self._window_lost_counted,
-                    detection=None if self._anomaly is None
-                    else self._anomaly.last_entropy_verdict)
-            # the lost-window guard resets at the TRUE window boundary —
-            # after the flush attempt — so a window where both a
-            # replayed batch and the readback die counts ONCE
-            self._window_lost_counted = False
+        tr = self._tracer
+        with tr.span("window.lock_wait"):
+            self._state_lock.acquire()
+        try:
+            out = self._close_window_locked(now)
+        finally:
+            self._state_lock.release()
         if self._anomaly is not None:
             # NO lock held: alert fan-out + bus publish + gauges
             self._anomaly.publish_pending()
@@ -1457,7 +1349,112 @@ class TpuSketchExporter(QueueWorkerExporter):
         if out is None:
             return None
         self.last_output = out
-        self._write_output(out, int(now))
+        with tr.span("window.output"):
+            self._write_output(out, int(now))
+        return out
+
+    def _close_window_locked(self, now: float):
+        """The window close under _state_lock: ship the open batch,
+        drain the feed, publish the snapshot, dispatch the flush program,
+        close the anomaly and audit windows. Returns the window's output,
+        or None."""
+        tr = self._tracer
+        if self._stager is not None:
+            # zero-copy: the open staging prefix ships as-is (slot
+            # contiguity — no repack); same put-under-lock shape as
+            # _submit_batch_locked, same back-pressure-not-deadlock
+            # argument
+            for sg in self._stager.flush():
+                self._feed.put(sg, -1)  # lint: disable=emit-under-lock
+        else:
+            for tb in self.batcher.flush():
+                self._submit_batch_locked(tb)
+        if self._feed is not None:
+            # barrier: every in-flight prefetched batch applies and
+            # fences before the window reads/resets state (feed.py
+            # ownership protocol). The feed thread never takes
+            # _state_lock, so holding it across the wait is safe.
+            with tr.span("window.drain"):
+                drained = self._feed.drain(timeout=60.0)
+            if not drained:
+                import logging
+                logging.getLogger(__name__).error(
+                    "feed drain timed out; window flushed against "
+                    "a possibly-advancing state")
+        self.windows += 1
+        was_degraded = self.degraded
+        if self.degraded:
+            # host fallback window: reduced-fidelity output, then
+            # probe the device for recovery
+            out = None if self._host is None \
+                else self._host.flush(self.cfg)
+            self._rows_at_flush = self.rows_in
+            self._probe_device_locked()
+        else:
+            # checkpoint the PRE-flush state (the window's
+            # accumulation): restore replays the window
+            # at-least-once; saving post-flush would snapshot a
+            # reset state and recover nothing. Cadence: every
+            # checkpoint_every-th window, and only if THIS window's
+            # accumulation is non-empty (a full npz per idle 1s
+            # window is not "low-overhead"). Rows in already-flushed
+            # windows need no snapshot — their output reached the
+            # store; restart loses at most the current accumulation,
+            # bounded by checkpoint_every windows of data.
+            dirty = self.rows_in != self._rows_at_flush
+            # snapshot bus: a disk publish on the checkpoint
+            # cadence, PLUS a subscriber-only (no npz) publish for
+            # every dirty window when the serving cache is listening
+            # — its staleness bound is one window, not
+            # checkpoint_every windows. No subscribers, no cadence
+            # hit => no device_get at all.
+            want_disk = (self.checkpointer is not None and dirty
+                         and self.windows % self.checkpoint_every == 0)
+            if want_disk or (dirty and self._snapbus.has_subscribers()):
+                # device_get of the state, subscriber delivery, and
+                # the npz when due
+                with tr.span("window.publish"):
+                    self._snapbus.publish(
+                        self.state, self.windows, wall_time=now,
+                        tags={"lossy": self._window_lost_counted},
+                        to_disk=want_disk)
+            self._rows_at_flush = self.rows_in
+            try:
+                # the dispatch only: the program runs on while the lock
+                # is released, and its output is read in window.output
+                with tr.span("window.flush"):
+                    self.state, out = self._flush_fn(self.state)
+            except RuntimeError:
+                # the window readback itself died on device: same
+                # classification + recovery as a batch failure
+                self._on_device_error_locked(0)
+                out = None
+        if self._anomaly is not None:
+            # anomaly plane: score the settled window
+            # BEFORE the audit closes so the detection audit can
+            # compare the device verdict against the exact shadow's
+            # twin scorer. Publication happens after the lock
+            # releases (publish_pending below) — bus subscribers
+            # and the exporter fan-out are emissions.
+            self._anomaly.close_window(
+                out, now=now, lossy=self._window_lost_counted,
+                degraded=was_degraded)
+        if self._audit is not None:
+            # accuracy observatory: compare the settled window
+            # against the exact shadow AT the window boundary (same
+            # lock, after the drain barrier — the shadow and the
+            # sketch saw the identical row set). A window with
+            # counted loss or on the degraded lane is audited too,
+            # tagged instead of alarmed on.
+            self._audit.close_window(
+                out, degraded=was_degraded,
+                lossy=self._window_lost_counted,
+                detection=None if self._anomaly is None
+                else self._anomaly.last_entropy_verdict)
+        # the lost-window guard resets at the TRUE window boundary —
+        # after the flush attempt — so a window where both a
+        # replayed batch and the readback die counts ONCE
+        self._window_lost_counted = False
         return out
 
     def _flush_pod_window(self, now: float) -> Optional[

@@ -4,8 +4,8 @@ The framework's core claim is zero-instrumentation observability of OTHER
 people's systems; this module is the half of self-observation the
 Countable registry (runtime/stats.py) doesn't cover — *where a batch's
 wall time goes*. Every hot-path stage (receiver dispatch, decode, queue
-dwell, kernel h2d/dispatch/device, window flush, export) records spans
-into:
+dwell, export, the feed's dispatch and starvation, window close)
+records spans into:
 
 - a fixed-size ring of completed spans (the "flight recorder": the last
   N spans survive for post-hoc inspection through the `spans` debug
@@ -18,17 +18,35 @@ into:
 Batch causality rides a monotonically increasing `batch_id`: the
 receiver stamps one on every frame, the decoder anchors its chunk to the
 first frame's id and hands it to the exporter fan-out, and the sketch
-exporters carry it into kernel attribution — so one slow batch can be
-followed receiver -> decode -> export -> kernel from the span ring.
+exporters carry it onto the feed thread's dispatch span — so one slow
+batch can be followed receiver -> decode -> export -> kernel from the
+span ring.
+
+Every context-manager span also lands in two more places while the
+tracer is enabled:
+
+- a `jax.profiler.TraceAnnotation` named exactly as the stage, so a
+  device trace's host plane carries the program's own stages on the
+  device's clock (an idle gap on the chip is then named by the host
+  stage that overlapped it). The annotation class is resolved in
+  `enable()`, so importing this module never imports JAX;
+- the thread's CPU seconds (`time.thread_time`) under the companion
+  stage `cpu.<stage>`, in the stage histograms only (not the ring, so
+  the ring's horizon does not shrink). Wall minus CPU is the time the
+  stage waited: for the interpreter lock, a lock, or a queue.
+
+The `observe()` form records wall time only, with no annotation.
 
 Cost discipline (the design constraint everything here bends around):
 
 - DISABLED (default): `span()` returns a shared no-op context manager —
-  zero allocations; hot call sites additionally guard on `tracer.enabled`
-  so not even an argument tuple is built.
-- ENABLED: one perf_counter pair + one histogram add + one ring store
-  per span, a few microseconds against millisecond-scale batch stages.
-  Spans are per *batch/frame*, never per record.
+  zero allocations, no clock read, no annotation; hot call sites
+  additionally guard on `tracer.enabled` so not even an argument tuple
+  is built.
+- ENABLED: one perf_counter pair, one thread_time pair, one annotation
+  and two histogram adds + one ring store per span, a few microseconds
+  against millisecond-scale batch stages. Spans are per *batch/frame*,
+  never per record.
 
 The ring is lock-free-ish: writers do an unsynchronized
 reserve-and-store (`i = n; n = i + 1`), relying on the GIL for memory
@@ -55,8 +73,6 @@ __all__ = ["HostDDSketch", "Tracer", "default_tracer", "GAUGE_HELP",
 # overlap_efficiency the device-busy proxy. The ISSUE 6 audit gauges
 # are the accuracy observatory's window verdicts (runtime/audit.py).
 GAUGE_HELP: Dict[str, str] = {
-    "tpu_h2d_mb_s": "sampled host->device transfer rate of the sketch "
-                    "lane (blocking measurement every Nth batch)",
     "tpu_transfers_per_batch": "device_put calls per TensorBatch on the "
                                "sketch lane; the coalesced feed holds "
                                "this at <= 1",
@@ -274,8 +290,19 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+def _annotation_class():
+    """The device profiler's host-annotation context manager, or None
+    where JAX is not installed. Looked up once, by `Tracer.enable()`."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 class _Span:
-    __slots__ = ("_tracer", "stage", "stream", "batch_id", "rows", "t0")
+    __slots__ = ("_tracer", "stage", "stream", "batch_id", "rows", "t0",
+                 "c0", "_ann")
 
     def __init__(self, tracer: "Tracer", stage: str, stream: str,
                  batch_id: int, rows: int) -> None:
@@ -285,15 +312,31 @@ class _Span:
         self.batch_id = batch_id
         self.rows = rows
         self.t0 = 0.0
+        self.c0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_Span":
+        ann = self._tracer._annotation
+        if ann is not None:
+            self._ann = ann(self.stage)
+            self._ann.__enter__()
+        # wall clock outside the CPU clock on both ends, so the CPU
+        # interval lies inside the wall interval
         self.t0 = time.perf_counter()
+        self.c0 = time.thread_time()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tracer.observe(self.stage, time.perf_counter() - self.t0,
-                             stream=self.stream, batch_id=self.batch_id,
-                             rows=self.rows, t0=self.t0)
+        cpu = time.thread_time() - self.c0
+        wall = time.perf_counter() - self.t0
+        tr = self._tracer
+        tr.observe(self.stage, wall, stream=self.stream,
+                   batch_id=self.batch_id, rows=self.rows, t0=self.t0)
+        # the two clocks tick apart: a thread that ran the whole span
+        # can read a hair more CPU than wall, which it cannot have used
+        tr._add("cpu." + self.stage, min(cpu, wall))
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         return False
 
 
@@ -322,6 +365,7 @@ class Tracer:
         self._lock = threading.Lock()   # reads + stage/gauge creation
         self._batch_seq = 0
         self._tls = threading.local()
+        self._annotation: Optional[Callable] = None
         # optional heartbeat hook (set by supervisor.default_supervisor):
         # every recorded span is proof of life for the recording thread,
         # feeding the deadman watchdog for free on traced hot paths
@@ -329,6 +373,8 @@ class Tracer:
 
     # -- lifecycle ---------------------------------------------------------
     def enable(self) -> None:
+        if self._annotation is None:
+            self._annotation = _annotation_class()
         self.enabled = True
 
     def disable(self) -> None:
@@ -362,8 +408,11 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     def span(self, stage: str, stream: str = "", batch_id: int = -1,
              rows: int = 0):
-        """Context manager timing one stage execution. Returns a shared
-        no-op when disabled (zero allocations)."""
+        """Context manager timing one stage execution: wall time into
+        the ring and `stage`'s histogram, the thread's CPU time into
+        `cpu.<stage>`'s, and a device-profiler annotation named `stage`
+        around it. Returns a shared no-op when disabled (zero
+        allocations)."""
         if not self.enabled:
             return _NOOP
         return _Span(self, stage, stream, batch_id, rows)
@@ -379,13 +428,7 @@ class Tracer:
             self.heartbeat()
         if batch_id < 0:
             batch_id = self.current_batch()
-        sk = self._stages.get(stage)
-        if sk is None:
-            with self._lock:
-                sk = self._stages.setdefault(
-                    stage, HostDDSketch(self._alpha, self._min_value_s,
-                                        self._buckets))
-        sk.add(dur_s)
+        self._add(stage, dur_s)
         # lock-free-ish reserve-and-store (see module docstring)
         i = self._n
         self._n = i + 1
@@ -393,6 +436,16 @@ class Tracer:
             stage, stream, batch_id,
             time.time() if t0 is None else time.time() - dur_s,
             dur_s, rows)
+
+    def _add(self, stage: str, dur_s: float) -> None:
+        """One sample into `stage`'s histogram (no ring entry)."""
+        sk = self._stages.get(stage)
+        if sk is None:
+            with self._lock:
+                sk = self._stages.setdefault(
+                    stage, HostDDSketch(self._alpha, self._min_value_s,
+                                        self._buckets))
+        sk.add(dur_s)
 
     def gauge(self, name: str, value: float) -> None:
         if not self.enabled:

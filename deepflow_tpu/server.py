@@ -20,6 +20,7 @@ Config (all keys optional):
       throttle_per_s: 50000
       tpu_sketch_window_s: 1.0
       app_red_window_s: 1.0
+      trace_enabled: true      # the flight recorder (runtime/tracing.py)
     querier:
       enabled: true
       port: 20416
@@ -105,6 +106,7 @@ class Server:
             store_max_bytes=ing_cfg.get("store_max_bytes", 100 << 30),
             tpu_sketch_window_s=ing_cfg.get("tpu_sketch_window_s"),
             app_red_window_s=ing_cfg.get("app_red_window_s"),
+            trace_enabled=ing_cfg.get("trace_enabled", True),
         ))
         if self.controller is not None:
             # in-process ingester enriches from this controller's model

@@ -142,7 +142,7 @@ def test_host_sync_negative_outside_device_path_and_in_helpers():
     # other modules may sync freely (checkpointing does, by design)
     assert analysis.run_on_sources({"runtime/checkpoint.py": DEVICE_SYNC}) \
         == []
-    sanctioned = DEVICE_SYNC.replace("def process", "def _to_device")
+    sanctioned = DEVICE_SYNC.replace("def process", "def _timed_update")
     assert analysis.run_on_sources(
         {"runtime/tpu_sketch.py": sanctioned}) == []
 

@@ -373,3 +373,108 @@ def test_make_coalesced_update_matches_sequential(rng):
         ref = flow_suite.update_packed(ref, lanes, mask, cfg)
     for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -------------------------------------- starvation and fence retirement
+
+class _Fence:
+    """A device fence stand-in: ready once `ready` is set."""
+
+    def __init__(self, ready: bool = True) -> None:
+        import threading
+        self.ready = threading.Event()
+        if ready:
+            self.ready.set()
+
+    def is_ready(self) -> bool:
+        return self.ready.is_set()
+
+    def block_until_ready(self):
+        self.ready.wait(10)
+        return self
+
+
+class _Batch:
+    valid = 100
+
+
+def _bare_feed(fences):
+    """A DeviceFeed whose every group 'dispatches' the next fence."""
+    from deepflow_tpu.runtime.feed import DeviceFeed, InFlight
+
+    it = iter(fences)
+    return DeviceFeed("test-feed",
+                      lambda group: InFlight(next(it), 100), depth=2)
+
+
+def _wait_for(pred, timeout=10.0):
+    import time
+    end = time.monotonic() + timeout
+    while not pred():
+        assert time.monotonic() < end, "condition never held"
+        time.sleep(0.002)
+
+
+def _recorder():
+    from deepflow_tpu.runtime.profiler import default_profiler
+    from deepflow_tpu.runtime.tracing import default_tracer
+
+    tr = default_tracer()
+    tr.reset()
+    tr.enable()
+    prof = default_profiler()
+    prof.reset()
+    return tr, prof
+
+
+def test_feed_wait_with_every_fence_ready_is_starvation():
+    """The regression: a fence that is ready but still inside the
+    depth-2 window used to keep the window 'busy' through the whole
+    wait, so the device read busy and the stall gauge read ~0. The feed
+    now retires ready fences before it waits, and the wait lands in
+    `feed.starved` and `tpu_feed_stall_seconds`."""
+    import time
+
+    tr, prof = _recorder()
+    feed = _bare_feed([_Fence(), _Fence()])
+    try:
+        feed.put(_Batch())
+        _wait_for(lambda: feed.fences == 1)
+        assert not feed._inflight
+        time.sleep(0.3)
+        feed.put(_Batch())
+        assert feed.drain(10)
+        starved = tr.stages()["feed.starved"]
+        assert starved.sum >= 0.25
+        assert prof.gauges()["tpu_feed_stall_seconds"] >= 0.25
+        # the device interval ended at retirement, not at the next batch
+        dev = [s for s in prof._snapshot() if s[0] == "device"]
+        assert len(dev) == 2 and all(s[3] < 0.2 for s in dev)
+    finally:
+        feed.close()
+
+
+def test_feed_leaves_an_unready_fence_in_flight():
+    """A fence that is not ready stays in flight (no blocking, no
+    starvation: the device still has work); the feed retires it on its
+    own once it is ready."""
+    import time
+
+    tr, prof = _recorder()
+    slow = _Fence(ready=False)
+    feed = _bare_feed([slow, _Fence()])
+    try:
+        feed.put(_Batch())
+        _wait_for(lambda: feed.groups == 1)
+        time.sleep(0.3)
+        assert len(feed._inflight) == 1 and feed.fences == 0
+        feed.put(_Batch())
+        _wait_for(lambda: feed.groups == 2)
+        assert feed.fences == 0
+        starved = tr.stages().get("feed.starved")
+        assert starved is None or starved.sum < 0.1
+        assert prof.stall_s < 0.1
+        slow.ready.set()
+        _wait_for(lambda: feed.fences == 2 and not feed._inflight)
+    finally:
+        feed.close()

@@ -107,6 +107,23 @@ def test_config_reload(tmp_path):
         srv.close()
 
 
+@pytest.mark.parametrize("key,want", [({}, True),
+                                      ({"trace_enabled": False}, False)])
+def test_ingester_trace_enabled_key(tmp_path, key, want):
+    """`ingester.trace_enabled` switches the flight recorder; absent, it
+    stays on."""
+    cfg = {"controller": {"enabled": False},
+           "ingester": {"port": 0, **key},
+           "querier": {"enabled": False}, "self_telemetry": False}
+    path = tmp_path / "server.yaml"
+    path.write_text(yaml.safe_dump(cfg))
+    srv = Server(str(path))
+    try:
+        assert srv.ingester.cfg.trace_enabled is want
+    finally:
+        srv.close()
+
+
 def test_controller_self_report(tmp_path):
     """Controller counters ride the DFSTATS self-telemetry loop into
     deepflow_system (reference: controller statsd report)."""

@@ -32,6 +32,137 @@ def test_disabled_span_is_shared_noop():
     assert tr.spans_recorded == 0
 
 
+def test_span_records_cpu_time_beside_wall_time():
+    """A context-manager span adds the thread's CPU seconds to
+    `cpu.<stage>`: never negative, never more than the wall time, in
+    the histograms only (the ring keeps wall spans)."""
+    tr = Tracer()
+    tr.enable()
+    with tr.span("work"):
+        end = time.perf_counter() + 0.01
+        while time.perf_counter() < end:      # on the CPU
+            pass
+        time.sleep(0.02)                      # off it
+    st = tr.stages()
+    wall, cpu = st["work"].sum, st["cpu.work"].sum
+    assert 0.0 <= cpu <= wall
+    assert cpu >= 0.005 and wall - cpu >= 0.015
+    assert [s["stage"] for s in tr.recent(10)] == ["work"]
+    tr.observe("queued", 0.01)                # the observe form: wall only
+    assert "cpu.queued" not in tr.stages()
+
+
+def test_disabled_tracer_reads_no_cpu_clock_and_opens_no_annotation(
+        monkeypatch):
+    """The disabled path keeps its contract — the shared no-op, no clock
+    read, no annotation — and the enabled path opens one annotation
+    named exactly as the stage (a recording factory stands in for the
+    device profiler's; no profiler session is started)."""
+    from deepflow_tpu.runtime import tracing
+
+    opened, clock = [], []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(self.name)
+
+        def __exit__(self, *exc):
+            opened.append("/" + self.name)
+
+    real = time.thread_time
+    monkeypatch.setattr(tracing, "_annotation_class", lambda: Annotation)
+    monkeypatch.setattr(tracing.time, "thread_time",
+                        lambda: clock.append(1) or real())
+    tr = Tracer()
+    with tr.span("decode"):
+        pass
+    assert tr.span("decode") is tr.span("export")
+    assert opened == [] and clock == []
+    tr.enable()
+    with tr.span("decode"):
+        with tr.span("decode.parse"):
+            pass
+    tr.observe("feed.starved", 0.001)
+    assert opened == ["decode", "decode.parse", "/decode.parse", "/decode"]
+    assert len(clock) == 4
+
+
+def test_tracing_module_imports_no_jax():
+    import subprocess
+    import sys
+
+    code = ("import sys; import deepflow_tpu.runtime.tracing; "
+            "sys.exit(any(m == 'jax' or m.startswith('jax.') "
+            "for m in sys.modules))")
+    assert subprocess.run([sys.executable, "-c", code]).returncode == 0
+
+
+@pytest.mark.parametrize("name", [
+    "sketch_update_dict", "sketch_update_lanes", "sketch_update_hits",
+    "sketch_update_news", "sketch_flush", "pod_merge", "anomaly_window"])
+def test_served_programs_carry_stable_xla_names(name):
+    """Every jitted program on the served path lowers to an XLA module
+    of a stable name, so a device trace's per-program time reads as
+    jit_<name> (not jit_prog or jit__lambda_)."""
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepflow_tpu.anomaly import AnomalyConfig, detectors
+    from deepflow_tpu.batch.schema import SKETCH_L4_SCHEMA
+    from deepflow_tpu.models import flow_dict, flow_suite
+    from deepflow_tpu.parallel.pod import PodFlowSuite
+    from deepflow_tpu.runtime.tpu_sketch import TpuSketchExporter
+
+    cfg = flow_suite.FlowSuiteConfig(cms_log2_width=10, ring_size=64,
+                                     top_k=8, hll_groups=16,
+                                     entropy_log2_buckets=6)
+    state = flow_suite.init(cfg)
+    rng = np.random.default_rng(0)
+    cols = {n: rng.integers(0, 50, 300).astype(dt)
+            for n, dt in SKETCH_L4_SCHEMA.columns}
+    packer = flow_dict.FlowDictPacker(capacity=1024, hits_batch=256)
+    news = packer.pack(cols) + packer.flush()
+    hits = packer.pack(cols) + packer.flush()
+    dstate = flow_dict.init_dict(packer.capacity)
+    if name == "sketch_update_dict":
+        sig = flow_dict.wire_signature(news)
+        flat = np.empty(flow_dict.wire_words(sig), np.uint32)
+        flow_dict.stage_wire(news, flat)
+        prog, args = flow_dict.make_wire_update(cfg, sig), (state, dstate,
+                                                            flat)
+    elif name == "sketch_update_lanes":
+        flat = np.zeros(flow_suite.coalesced_lanes_words(1, 256), np.uint32)
+        prog, args = flow_suite.make_coalesced_update(cfg, 1, 256), (state,
+                                                                     flat)
+    elif name == "pod_merge":
+        prog = PodFlowSuite._make_merge(SimpleNamespace(cfg=cfg), 2)
+        args = (jax.tree.map(lambda x: jnp.stack([x, x]), state),)
+    elif name == "anomaly_window":
+        acfg = AnomalyConfig()
+        out = jax.eval_shape(lambda s: flow_suite.flush(s, cfg), state)[1]
+        prog = detectors.make_window_step(acfg)
+        args = (detectors.init(acfg), out.entropies, out.topk_counts,
+                out.service_cardinality, out.rows)
+    else:
+        exp = TpuSketchExporter(store=None, cfg=cfg, batch_rows=256,
+                                wire="dict", prefetch_depth=0)
+        kind = {"sketch_update_hits": "hits",
+                "sketch_update_news": "news"}.get(name)
+        if kind is None:
+            prog, args = exp._flush_fn, (exp.state,)
+        else:
+            _, plane, n = next(w for w in news + hits if w[0] == kind)
+            prog = exp._update_hits if kind == "hits" else exp._update_news
+            args = (exp.state, exp._dict_state, plane, np.uint32(n))
+    text = prog.lower(*args).as_text()
+    assert f"module @jit_{name} " in text, text[:200]
+
+
 def test_span_nesting_records_both_stages():
     tr = Tracer()
     tr.enable()
@@ -40,7 +171,7 @@ def test_span_nesting_records_both_stages():
         with tr.span("inner", batch_id=7):
             time.sleep(0.001)
     lat = tr.latency()
-    assert set(lat) == {"outer", "inner"}
+    assert set(lat) == {"outer", "inner", "cpu.outer", "cpu.inner"}
     assert lat["outer"]["max_ms"] >= lat["inner"]["max_ms"]
     assert lat["inner"]["max_ms"] >= 1.0
     spans = tr.recent(10)
@@ -138,13 +269,13 @@ def test_render_metrics_is_strictly_valid():
     tr.enable()
     for i in range(100):
         tr.observe("decode", 0.001 * (i + 1), stream="l4")
-    tr.gauge("tpu_h2d_mb_s", 123.4)
+    tr.gauge("tpu_transfers_per_batch", 2.5)
     text = render_metrics(reg, tr)
     assert validate_exposition(text) == []
     assert "deepflow_queue_in_in" in text
     assert 'stage="decode"' in text
     assert 'le="+Inf"' in text
-    assert "deepflow_trace_tpu_h2d_mb_s 123.4" in text
+    assert "deepflow_trace_tpu_transfers_per_batch 2.5" in text
     # non-numeric countable values ride an info sample, never a bare
     # unparseable value
     assert 'mode="local"' in text
@@ -215,7 +346,8 @@ def test_batch_id_propagates_receiver_to_exporter(tmp_path):
     """One frame's receiver-stamped batch id must reappear on the
     decode span and on the export span (causality across two thread
     hops), and `trace latency` / the Prometheus endpoint must expose
-    non-zero receiver/decode/export/kernel/window stages."""
+    non-zero receiver/decode/export/kernel/window stages, beside the
+    child stages, the feed's starvation and the CPU companions."""
     from deepflow_tpu.enrich.platform_data import PlatformDataManager
     from deepflow_tpu.pipelines import Ingester, IngesterConfig
     from deepflow_tpu.runtime.debug import debug_request
@@ -227,6 +359,9 @@ def test_batch_id_propagates_receiver_to_exporter(tmp_path):
                                   tpu_sketch_window_s=0.2),
                    platform=PlatformDataManager())
     ing.start()
+    # a listener on the snapshot bus, as the querier's cache is, so
+    # each window close publishes
+    unsubscribe = ing.tpu_sketch.snapshot_bus.subscribe(lambda snap: None)
     try:
         assert tracer.enabled
         with socket.create_connection(("127.0.0.1", ing.port),
@@ -235,12 +370,14 @@ def test_batch_id_propagates_receiver_to_exporter(tmp_path):
                 s.sendall(_l4_frame(seed=i, seq=i + 1))
         deadline = time.time() + 30
         needed = {"receiver", "decode", "export", "kernel", "window"}
+        children = {"decode.parse", "export.lock_wait", "window.drain",
+                    "window.publish", "feed.starved", "cpu.decode"}
         while time.time() < deadline:
-            if needed <= set(tracer.latency()):
+            if needed | children <= set(tracer.latency()):
                 break
             time.sleep(0.1)
         lat = tracer.latency()
-        assert needed <= set(lat), sorted(lat)
+        assert needed | children <= set(lat), sorted(lat)
         for stage in needed:
             assert lat[stage]["p99_ms"] > 0.0, stage
             assert lat[stage]["p50_ms"] <= lat[stage]["p95_ms"] \
@@ -261,8 +398,9 @@ def test_batch_id_propagates_receiver_to_exporter(tmp_path):
                               count=50)["data"]["spans"]
         assert spans and all("dur_ms" in s_ for s_ in spans)
         rrt = debug_request("rrt", port=ing.debug.port)["data"]
-        assert "tpu_h2d_mb_s" in rrt["gauges"]
-        assert any(k.startswith("kernel") for k in rrt["kernel_stages"])
+        assert "tpu_transfers_per_batch" in rrt["gauges"]
+        assert any(k.startswith("tpu_compile_s_") for k in rrt["gauges"])
+        assert {"kernel", "kernel.compile"} <= set(rrt["kernel_stages"])
         # the live Prometheus endpoint serves the histograms, strictly
         # valid, with the kernel stage present
         with urllib.request.urlopen(
@@ -273,6 +411,7 @@ def test_batch_id_propagates_receiver_to_exporter(tmp_path):
         assert 'stage="kernel"' in text
         assert "deepflow_receiver_rx_frames" in text
     finally:
+        unsubscribe()
         ing.close()
 
 
