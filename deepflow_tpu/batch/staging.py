@@ -469,6 +469,9 @@ class DictWireStager:
         self.pool_hits = 0
         self.recycled = 0
         self.epoch_drops = 0
+        # rows whose flow keys the packer resolved natively / in Python
+        self.resolve_native_rows = 0
+        self.resolve_python_rows = 0
 
     # -- producer side (the exporter worker, serialized) -------------------
     def put(self, cols: Dict[str, np.ndarray]) -> List[StagedWireGroup]:
@@ -556,6 +559,10 @@ class DictWireStager:
             # partition — and therefore ring phase — to the inline path)
             wire = self._packer.pack(batch)
             wire += self._packer.flush()
+            if self._packer.resolve_native:
+                self.resolve_native_rows += n
+            else:
+                self.resolve_python_rows += n
             self._fill = 0       # pack() consumed the accumulation
             self._wire.extend(wire)
             self._batches += 1
@@ -619,7 +626,9 @@ class DictWireStager:
              "staging_pool_hits": self.pool_hits,
              "staging_recycled": self.recycled,
              "dict_epoch": self.epoch,
-             "dict_epoch_drops": self.epoch_drops}
+             "dict_epoch_drops": self.epoch_drops,
+             "dict_resolve_native_rows": self.resolve_native_rows,
+             "dict_resolve_python_rows": self.resolve_python_rows}
         if self._pack_pool is not None:
             c.update(self._pack_pool.counters())
         return c

@@ -1,13 +1,11 @@
 """ctypes binding for the C++ columnar decoder (native_src/decoder.cc).
 
-Compiles the shared library on first use (g++ is part of the toolchain)
-and exposes `decode_l4_payloads`, a drop-in fast path for the flow_log
-decode stage. The .so's file name carries a hash of the source, the
-compiler flags and the host CPU's flags (`build_key`): `-march=native`
-code built on another machine, or from another source, is never
-loaded — it simply has another name, and this host builds its own.
-`available()` is False when no compiler exists; the flow_log pipeline
-then logs the `build_error()` and keeps the pure-Python decoder.
+Compiles the shared library on first use (g++ is part of the toolchain;
+utils/native_build.py builds and loads it under a key of its source,
+flags and host CPU) and exposes `decode_l4_payloads`, a drop-in fast
+path for the flow_log decode stage. `available()` is False when no
+compiler exists; the flow_log pipeline then logs the `build_error()`
+and keeps the pure-Python decoder.
 
 The native ABI emits two plane blocks per batch — a [N32, capacity] u32
 block for every u32/i32 schema column and a [N64, capacity] u64 block for
@@ -18,60 +16,15 @@ batch/schema.py L4_SCHEMA order exactly.
 from __future__ import annotations
 
 import ctypes
-import hashlib
 import os
-import platform
-import subprocess
-import threading
 from typing import Dict, Iterable, Optional, Tuple
 
 import numpy as np
 
 from deepflow_tpu.batch.schema import L4_SCHEMA
+from deepflow_tpu.utils.native_build import NativeLib
 
 _SRC = os.path.join(os.path.dirname(__file__), "native_src", "decoder.cc")
-# -O3 -march=native -funroll-loops is load-bearing: the varint walk
-# runs ~3x faster than at generic -O2
-CXXFLAGS = ("-O3", "-march=native", "-funroll-loops", "-shared", "-fPIC",
-            "-std=c++17")
-
-
-def _cpu_flags() -> str:
-    """What -march=native compiles for: the machine and its CPU flags."""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    return platform.machine() + ":" + " ".join(
-                        sorted(line.split(":", 1)[1].split()))
-    except OSError:
-        pass
-    return platform.machine() + ":" + platform.processor()
-
-
-def build_key(src: str = _SRC, flags: Tuple[str, ...] = CXXFLAGS,
-              cpu: Optional[str] = None) -> str:
-    """Hash of everything the binary depends on: source bytes, compiler
-    flags and the host CPU's flags."""
-    h = hashlib.sha256()
-    with open(src, "rb") as f:
-        h.update(f.read())
-    h.update(b"\0" + " ".join(flags).encode())
-    h.update(b"\0" + (_cpu_flags() if cpu is None else cpu).encode())
-    return h.hexdigest()[:16]
-
-
-def _so_path(key: Optional[str] = None) -> str:
-    """Build cache location for the compiled decoder. Default: beside
-    the source. `DEEPFLOW_TPU_NATIVE_DIR` overrides for read-only
-    installs (the docker-compose manifest bind-mounts the repo :ro and
-    points this at a writable volume)."""
-    d = os.environ.get("DEEPFLOW_TPU_NATIVE_DIR") or \
-        os.path.join(os.path.dirname(__file__), "native_src")
-    return os.path.join(d, f"_native_decoder-{key or build_key()}.so")
-
-
-_SO = _so_path()
 
 # schema columns partitioned by plane width (order preserved per plane)
 L4_COLS32 = tuple((n, d) for n, d in L4_SCHEMA.columns
@@ -79,77 +32,44 @@ L4_COLS32 = tuple((n, d) for n, d in L4_SCHEMA.columns
 L4_COLS64 = tuple((n, d) for n, d in L4_SCHEMA.columns
                   if np.dtype(d).itemsize == 8)
 
-_lock = threading.Lock()
-_lib: Optional[ctypes.CDLL] = None
-_build_error: Optional[str] = None
 
-
-def _build() -> Optional[str]:
-    """Compile unless this host's keyed binary exists; returns an error
-    string or None."""
-    if os.path.exists(_SO):
-        return None
-    # cache-dir creation failures degrade like every other build failure
-    # (pure-Python fallback + build_error()), never a startup crash
-    try:
-        os.makedirs(os.path.dirname(_SO), exist_ok=True)
-    except OSError as e:
-        return f"native cache dir: {e}"
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    cmd = ["g++", *CXXFLAGS, _SRC, "-o", tmp, "-lpthread"]
-    try:
-        proc = subprocess.run(cmd, capture_output=True, text=True,
-                              timeout=120)
-    except (OSError, subprocess.TimeoutExpired) as e:
-        return str(e)
-    if proc.returncode != 0:
-        return proc.stderr[-2000:]
-    os.replace(tmp, _SO)
+def _bind(lib: ctypes.CDLL) -> Optional[str]:
+    lib.df_decode_l4.restype = ctypes.c_long
+    lib.df_decode_l4.argtypes = [
+        ctypes.c_char_p, ctypes.c_size_t,
+        ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_long,
+        ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_size_t),
+    ]
+    lib.df_decode_l4_mt.restype = ctypes.c_long
+    lib.df_decode_l4_mt.argtypes = [
+        ctypes.c_char_p, ctypes.c_size_t,
+        ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_long, ctypes.c_int,
+        ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_size_t),
+    ]
+    lib.df_n_l4_cols.restype = ctypes.c_int
+    lib.df_n_l4_cols64.restype = ctypes.c_int
+    n32, n64 = lib.df_n_l4_cols(), lib.df_n_l4_cols64()
+    if n32 != len(L4_COLS32) or n64 != len(L4_COLS64):
+        return (f"column count mismatch: native {n32}+{n64} vs "
+                f"schema {len(L4_COLS32)}+{len(L4_COLS64)}")
     return None
 
 
-def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _build_error
-    with _lock:
-        if _lib is not None or _build_error is not None:
-            return _lib
-        _build_error = _build()
-        if _build_error is not None:
-            return None
-        lib = ctypes.CDLL(_SO)
-        lib.df_decode_l4.restype = ctypes.c_long
-        lib.df_decode_l4.argtypes = [
-            ctypes.c_char_p, ctypes.c_size_t,
-            ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint64),
-            ctypes.c_long,
-            ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_size_t),
-        ]
-        lib.df_decode_l4_mt.restype = ctypes.c_long
-        lib.df_decode_l4_mt.argtypes = [
-            ctypes.c_char_p, ctypes.c_size_t,
-            ctypes.POINTER(ctypes.c_uint32), ctypes.POINTER(ctypes.c_uint64),
-            ctypes.c_long, ctypes.c_int,
-            ctypes.POINTER(ctypes.c_long), ctypes.POINTER(ctypes.c_size_t),
-        ]
-        lib.df_n_l4_cols.restype = ctypes.c_int
-        lib.df_n_l4_cols64.restype = ctypes.c_int
-        n32, n64 = lib.df_n_l4_cols(), lib.df_n_l4_cols64()
-        if n32 != len(L4_COLS32) or n64 != len(L4_COLS64):
-            _build_error = (
-                f"column count mismatch: native {n32}+{n64} vs "
-                f"schema {len(L4_COLS32)}+{len(L4_COLS64)}")
-            return None
-        _lib = lib
-        return _lib
+_LIB = NativeLib(_SRC, "_native_decoder", _bind, libs=("-lpthread",))
 
 
 def available() -> bool:
-    return _load() is not None
+    return _LIB.available()
 
 
 def build_error() -> Optional[str]:
-    _load()
-    return _build_error
+    return _LIB.build_error()
+
+
+def build_key() -> str:
+    return _LIB.key()
 
 
 def decode_l4_into(payload: bytes, out32: np.ndarray, out64: np.ndarray,
@@ -159,9 +79,10 @@ def decode_l4_into(payload: bytes, out32: np.ndarray, out64: np.ndarray,
     consumed_bytes). The buffers can be reused across calls — the bench's
     double-buffer feed path (reference: server/libs/receiver/receiver.go
     tiered buffer pools play this role)."""
-    lib = _load()
+    lib = _LIB.load()
     if lib is None:
-        raise RuntimeError(f"native decoder unavailable: {_build_error}")
+        raise RuntimeError(
+            f"native decoder unavailable: {_LIB.build_error()}")
     assert out32.ndim == 2 and out32.shape[0] == len(L4_COLS32) and \
         out32.dtype == np.uint32 and out32.flags.c_contiguous
     assert out64.ndim == 2 and out64.shape[0] == len(L4_COLS64) and \

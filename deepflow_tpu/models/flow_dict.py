@@ -38,6 +38,7 @@ rather than a padded news plane per batch.
 
 from __future__ import annotations
 
+import logging
 from collections import OrderedDict
 from typing import Dict, List, NamedTuple, Tuple
 
@@ -46,7 +47,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from deepflow_tpu.models import flow_suite
+from deepflow_tpu.models import flow_dict_native, flow_suite
+from deepflow_tpu.models.flow_dict_native import Resolution
 from deepflow_tpu.models.flow_suite import (FlowSuiteConfig,
                                             FlowSuiteState, unpack_lanes)
 from deepflow_tpu.utils.twinmark import host_twin_of
@@ -317,6 +319,75 @@ def make_wire_update(cfg: FlowSuiteConfig,
     return jax.jit(sketch_update_dict, donate_argnums=(0, 1))
 
 
+class PyKeyTable:
+    """Pure-Python key -> dictionary-index table: the oracle for the
+    native one (flow_dict_native.KeyTable, same contract, same output),
+    and the packer's resolver only where the native build is missing.
+
+    One `resolve` per record batch: the batch's distinct keys resolve
+    once each, in np.unique's order over the 16-byte key view; a
+    present key moves to the MRU end in that order; a new key takes
+    the lowest free index, or once none is free evicts the LRU head
+    and takes its index."""
+
+    native = False
+
+    def __init__(self, capacity: int) -> None:
+        self.capacity = capacity
+        self._idx: "OrderedDict[bytes, int]" = OrderedDict()  # LRU
+        self._free = list(range(capacity - 1, -1, -1))        # pop() asc
+
+    def __len__(self) -> int:
+        return len(self._idx)
+
+    def resolve(self, keys: np.ndarray) -> Resolution:
+        """(n, 4) u32 key words -> Resolution."""
+        kbytes = np.ascontiguousarray(keys, np.uint32).view("V16").ravel()
+        uniq, first, inverse = np.unique(
+            kbytes, return_index=True, return_inverse=True)
+        size = len(self._idx)
+        if len(uniq) >= self.capacity:
+            return Resolution(None, None, len(uniq), size, 0)
+        # resolve each UNIQUE key once (python cost scales with distinct
+        # flows, not records); LRU order refreshed per appearance
+        uidx = np.empty(len(uniq), np.uint32)
+        is_new = np.zeros(len(uniq), bool)
+        evicted = 0
+        for i, kb in enumerate(uniq):
+            k = bytes(kb)
+            got = self._idx.get(k)
+            if got is None:
+                is_new[i] = True
+                if not self._free:
+                    _, old_idx = self._idx.popitem(last=False)
+                    evicted += 1
+                    self._free.append(old_idx)
+                got = self._free.pop()
+                self._idx[k] = got
+            else:
+                self._idx.move_to_end(k)
+            uidx[i] = got
+        return Resolution(uidx[inverse], first[is_new], len(uniq), size,
+                          evicted)
+
+
+_log = logging.getLogger(__name__)
+_python_resolution_logged = False
+
+
+def key_table(capacity: int):
+    """The packer's key table: native wherever it builds. The fallback
+    logs its build error once per process."""
+    global _python_resolution_logged
+    if flow_dict_native.available():
+        return flow_dict_native.KeyTable(capacity)
+    if not _python_resolution_logged:
+        _python_resolution_logged = True
+        _log.warning("native flow dict unavailable, resolving dict-wire "
+                     "keys in Python: %s", flow_dict_native.build_error())
+    return PyKeyTable(capacity)
+
+
 class FlowDictPacker:
     """Host side: streaming records -> ordered news/hits wire batches.
 
@@ -331,13 +402,16 @@ class FlowDictPacker:
 
     Index reuse after eviction is made safe by the PRE-DRAIN in
     pack(): eviction can only happen once the dictionary is full,
-    pack() flushes every buffered hit row before resolving keys
+    pack() flushes every buffered hit row ahead of the call's news
     whenever this call could fill it, and the current call's hit rows
-    are appended only after every key has resolved — so at any
-    eviction, no emitted-or-buffered hit row references the freed
-    index, and the index's next tenant is scattered (its news batch)
-    before any hit row referencing the reused index can exist.
-    `_assign` enforces the invariant rather than trusting it.
+    are appended only after every key has resolved — so when a reused
+    index's next tenant is scattered (its news batch), no emitted-or-
+    buffered hit row still references the index's old tenant. pack()
+    enforces the invariant rather than trusting it.
+
+    Key resolution is one call per batch into the key table
+    (`key_table`): native, releasing the interpreter lock, wherever it
+    builds; `resolve_native` says which one this packer holds.
 
     The packer is windowless: it never needs flushing on window
     boundaries because sketch windows close on the DEVICE (flush
@@ -348,7 +422,7 @@ class FlowDictPacker:
     def __init__(self, capacity: int = 1 << 20,
                  hits_batch: int = 1 << 17, news_batch: int = 1 << 13):
         if capacity <= hits_batch:
-            # the eviction-safety argument (_assign) needs an LRU head
+            # the eviction-safety argument (pack()) needs an LRU head
             # that the current call has not touched; a dictionary
             # smaller than one wire batch cannot guarantee it
             raise ValueError("capacity must exceed hits_batch")
@@ -357,8 +431,8 @@ class FlowDictPacker:
         self.capacity = capacity
         self.hits_batch = hits_batch
         self.news_batch = news_batch
-        self._idx: "OrderedDict[bytes, int]" = OrderedDict()  # LRU
-        self._free = list(range(capacity - 1, -1, -1))        # pop() asc
+        self._keys = key_table(capacity)
+        self.resolve_native = self._keys.native
         self._hit_idx: List[np.ndarray] = []     # buffered hit rows
         self._hit_pkts: List[np.ndarray] = []
         self._hit_count = 0
@@ -436,28 +510,6 @@ class FlowDictPacker:
 
     # -- packing ------------------------------------------------------------
 
-    def _assign(self, key: bytes) -> int:
-        """Index for a NEW key, evicting LRU when full.
-
-        Eviction is only reached with the hit buffer empty (pack()'s
-        pre-drain — enforced here, since reusing an index a buffered
-        hit still references would gather the new tenant's key), and
-        pops the LRU head, which is always a key NOT touched by the
-        current call (touched keys re-order to the tail as they
-        resolve; the `len(uniq) < capacity` guard in pack() means an
-        untouched one exists)."""
-        if not self._free:
-            if self._hit_count:
-                raise RuntimeError(
-                    "flow dict eviction with hits buffered: pack() "
-                    "must pre-drain first (bug, not load)")
-            _, old_idx = self._idx.popitem(last=False)
-            self.evictions += 1
-            self._free.append(old_idx)
-        idx = self._free.pop()
-        self._idx[key] = idx
-        return idx
-
     def pack(self, cols: Dict[str, np.ndarray]
              ) -> List[Tuple[str, np.ndarray, int]]:
         """One record batch -> ordered wire batches [(kind, plane, n)].
@@ -476,41 +528,31 @@ class FlowDictPacker:
                       << u32(16)) | (cols["port_dst"].astype(u32)
                                      & u32(0xFFFF))
         keys[:, 3] = cols["proto"].astype(u32) & u32(0xFF)   # raw byte
-        kbytes = np.ascontiguousarray(keys).view("V16").ravel()  # (n,)
-        uniq, first, inverse = np.unique(
-            kbytes, return_index=True, return_inverse=True)
-        if len(uniq) >= self.capacity:
+        res = self._keys.resolve(keys)
+        if res.rec_idx is None:
             # with fewer uniques than capacity, a full dict always
             # holds >= 1 key untouched by this call, so the LRU head
-            # _assign evicts can never be a key whose index this
-            # call's already-computed hit rows reference
+            # an eviction takes can never be a key whose index this
+            # call's hit rows reference
             raise ValueError(
-                f"{len(uniq)} unique flows in one pack() call >= "
+                f"{res.n_uniq} unique flows in one pack() call >= "
                 f"dictionary capacity {self.capacity}")
-        # resolve each UNIQUE key once (python cost scales with new
-        # flows, not records); LRU order refreshed per appearance
-        uidx = np.empty(len(uniq), u32)
-        is_new = np.zeros(len(uniq), bool)
-        if len(self._idx) + len(uniq) > self.capacity and self._hit_count:
-            # eviction is possible this call: drain buffered hits
-            # FIRST so an old reference can never gather a reused
-            # index's new tenant (conservative — len(uniq) bounds the
-            # truly-new count from above)
+        if res.size_before + res.n_uniq > self.capacity and self._hit_count:
+            # eviction was possible this call: drain buffered hits
+            # ahead of its news so an old reference can never gather a
+            # reused index's new tenant (conservative — n_uniq bounds
+            # the truly-new count from above)
             self._flush_hits(out, partial=True)
-        for i, kb in enumerate(uniq):
-            k = bytes(kb)
-            got = self._idx.get(k)
-            if got is None:
-                is_new[i] = True
-                uidx[i] = self._assign(k)
-            else:
-                self._idx.move_to_end(k)
-                uidx[i] = got
-        rec_idx = uidx[inverse]
+        if res.evicted and self._hit_count:
+            raise RuntimeError(
+                "flow dict eviction with hits buffered: pack() "
+                "must pre-drain first (bug, not load)")
+        self.evictions += res.evicted
+        rec_idx = res.rec_idx
         # news rows = the FIRST occurrence of each new unique key; all
         # other records are hits (including later same-batch records
         # of a new key — their news is emitted first, below)
-        news_rows = first[is_new]
+        news_rows = res.news_rows
         self._emit_news(out, rec_idx[news_rows], keys[news_rows],
                         pkts[news_rows])
         hit_mask = np.ones(n, bool)

@@ -9,7 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepflow_tpu.models import flow_dict, flow_suite
+from deepflow_tpu.models import flow_dict, flow_dict_native, flow_suite
 from deepflow_tpu.models.flow_dict import FlowDictPacker
 from deepflow_tpu.models.flow_suite import FlowSuiteConfig
 
@@ -230,3 +230,137 @@ def test_padding_rows_do_not_count():
     dstate = flow_dict.init_dict(packer.capacity)
     state, _ = flow_dict.apply_batches(state, dstate, wire, CFG)
     assert int(state.rows_seen) == 8
+
+
+# -- native key resolution == the pure-Python oracle -----------------------
+
+def _fresh_mix(pool, n, fresh, rng):
+    """n records Zipf over `pool`, a `fresh` share given new source
+    ports and addresses (keys never seen before)."""
+    m = len(pool["ip_src"])
+    picks = (rng.zipf(1.25, n) - 1) % m
+    cols = {k: v[picks].copy() for k, v in pool.items()}
+    f = rng.random(n) < fresh
+    cols["port_src"][f] = rng.integers(0, 1 << 16, f.sum(), dtype=np.uint32)
+    cols["ip_src"][f] = rng.integers(0, 1 << 32, f.sum(), dtype=np.uint32)
+    return cols
+
+
+def _distinct(pool, lo, hi):
+    return {k: v[lo:hi] for k, v in pool.items()}
+
+
+def _oracle_pool(n, seed):
+    """Half the flows run between 4 x 4 hosts, so many keys share their
+    first 8 bytes and only the ports order them."""
+    pool = _pool(n, seed=seed)
+    rng = np.random.default_rng(seed + 1)
+    hosts = rng.integers(0, 1 << 32, 8, dtype=np.uint32)
+    few = rng.random(n) < 0.5
+    pool["ip_src"][few] = hosts[rng.integers(0, 4, few.sum())]
+    pool["ip_dst"][few] = hosts[rng.integers(4, 8, few.sum())]
+    return pool
+
+
+def _sequence(case, seed):
+    """(packer kwargs, ops): ops are ("pack", cols), ("flush",) or
+    ("reset",), the last a device-state restore's fresh packer."""
+    rng = np.random.default_rng(seed)
+    if case == "resident":
+        pool = _oracle_pool(2048, seed)
+        kw = dict(capacity=8192, hits_batch=2048, news_batch=256)
+        ops = [("pack", _fresh_mix(pool, 3000, 0.0, rng))
+               for _ in range(8)]
+    elif case == "churn":
+        # half fresh keys through a 1,024-slot table: fills, then
+        # evicts on every call
+        pool = _oracle_pool(512, seed)
+        kw = dict(capacity=1024, hits_batch=512, news_batch=128)
+        ops = [("pack", _fresh_mix(pool, 900, 0.5, rng))
+               for _ in range(10)]
+    elif case == "capacity":
+        # the second batch's distinct keys reach capacity: refused,
+        # state untouched, and the stream goes on
+        pool = _oracle_pool(600, seed)
+        kw = dict(capacity=256, hits_batch=128, news_batch=64)
+        ops = [("pack", _distinct(pool, 0, 200)),
+               ("pack", _distinct(pool, 200, 500)),
+               ("pack", _fresh_mix(_distinct(pool, 100, 300), 250, 0.0,
+                                   rng)),
+               ("flush",)]
+    elif case == "predrain":
+        # hits stay buffered across calls (hits_batch > a call's
+        # records) until a call that could fill the table drains them
+        # ahead of its news
+        pool = _oracle_pool(1400, seed)
+        kw = dict(capacity=1024, hits_batch=1000, news_batch=128)
+        warm = _distinct(pool, 0, 900)
+        ops = [("pack", warm),
+               ("pack", _fresh_mix(warm, 300, 0.0, rng)),
+               ("pack", _distinct(pool, 900, 1100)),
+               ("pack", _fresh_mix(_distinct(pool, 500, 1100), 600, 0.1,
+                                   rng)),
+               ("flush",)]
+    else:                                        # reset
+        pool = _oracle_pool(1024, seed)
+        kw = dict(capacity=2048, hits_batch=512, news_batch=128)
+        mix = [("pack", _fresh_mix(pool, 1500, 0.2, rng)) for _ in range(3)]
+        ops = mix[:2] + [("reset",)] + mix[2:] + [("flush",)]
+    return kw, ops
+
+
+def _emit(kw, ops):
+    """Run ops; returns [(op index, kind, plane bytes, shape, n)], the
+    per-op errors and each packer generation's evictions."""
+    packer = FlowDictPacker(**kw)
+    wire, errors, evictions = [], [], []
+    for i, op in enumerate(ops):
+        if op[0] == "reset":
+            evictions.append(packer.evictions)
+            packer = FlowDictPacker(**kw)
+            continue
+        try:
+            out = packer.pack(op[1]) if op[0] == "pack" else packer.flush()
+        except ValueError as e:
+            errors.append((i, str(e)))
+            continue
+        wire += [(i, k, p.tobytes(), p.shape, n) for k, p, n in out]
+    evictions.append(packer.evictions)
+    return wire, errors, evictions, packer
+
+
+@pytest.mark.skipif(not flow_dict_native.available(),
+                    reason=f"no native flow dict: "
+                    f"{flow_dict_native.build_error()}")
+@pytest.mark.parametrize("seed", [5, 2147483651])
+@pytest.mark.parametrize("case", ["resident", "churn", "capacity",
+                                  "predrain", "reset"])
+def test_native_resolution_emits_the_python_wire(case, seed, monkeypatch):
+    """The native key table and the pure-Python oracle emit the same
+    wire, byte for byte: every plane, its n, their order, the refused
+    calls and the evictions."""
+    kw, ops = _sequence(case, seed % (1 << 32))
+    native = _emit(kw, ops)
+    assert native[3].resolve_native
+    with monkeypatch.context() as m:
+        m.setattr(flow_dict_native, "available", lambda: False)
+        m.setattr(flow_dict, "_python_resolution_logged", True)
+        python = _emit(kw, ops)
+    assert not python[3].resolve_native
+    assert native[:3] == python[:3]
+    wire, errors, evictions, _ = python
+    assert any(k == "news" for _, k, _, _, _ in wire)
+    if case == "churn":
+        assert evictions[0] > 0
+    if case == "capacity":
+        assert [i for i, _ in errors] == [1]
+        assert "unique flows" in errors[0][1]
+    else:
+        assert errors == []
+    if case == "predrain":
+        # the call that could fill the table opens with the drained
+        # partial hits plane, ahead of its own news
+        first = [(k, n) for i, k, _, _, n in wire if i == 2][0]
+        assert first[0] == "hits" and 0 < first[1] < kw["hits_batch"]
+    if case == "reset":
+        assert len(evictions) == 2

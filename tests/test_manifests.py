@@ -100,10 +100,10 @@ def test_native_decoder_build_dir_override(tmp_path, monkeypatch):
     installs: the compose manifest mounts the repo :ro)."""
     from deepflow_tpu.decode import native
     monkeypatch.setenv("DEEPFLOW_TPU_NATIVE_DIR", str(tmp_path / "cache"))
-    p = native._so_path()
+    p = native._LIB.path()
     assert p.startswith(str(tmp_path / "cache"))
     monkeypatch.delenv("DEEPFLOW_TPU_NATIVE_DIR")
-    assert native._so_path().endswith(os.path.join(
+    assert native._LIB.path().endswith(os.path.join(
         "native_src", f"_native_decoder-{native.build_key()}.so"))
 
 
@@ -113,9 +113,7 @@ def test_native_decoder_unwritable_cache_degrades(tmp_path, monkeypatch):
     from deepflow_tpu.decode import native
     blocked = tmp_path / "file-not-dir"
     blocked.write_text("")   # a FILE where the cache dir should go
-    monkeypatch.setattr(native, "_SO",
-                        str(blocked / "sub" / "_native_decoder.so"))
-    err = native._build()
+    err = native._LIB.build(str(blocked / "sub" / "_native_decoder.so"))
     assert err is not None and "native cache dir" in err
 
 

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from deepflow_tpu.decode import columnar, native
+from deepflow_tpu.utils import native_build
 from deepflow_tpu.replay.generator import SyntheticAgent
 from deepflow_tpu.wire.codec import pack_pb_records
 
@@ -17,14 +18,16 @@ def test_binary_name_is_derived_from_source_flags_and_cpu(tmp_path):
     the source, the compiler flags or the host CPU gives another name,
     so a binary built elsewhere is never picked up."""
     key = native.build_key()
-    assert native._SO.endswith(f"_native_decoder-{key}.so")
-    assert native.build_key(cpu=native._cpu_flags()) == key
+    assert native._LIB.path().endswith(f"_native_decoder-{key}.so")
+    assert native_build.build_key(native._SRC,
+                                  cpu=native_build.cpu_flags()) == key
     other_src = tmp_path / "decoder.cc"
     other_src.write_bytes(open(native._SRC, "rb").read() + b"\n")
-    assert native.build_key(src=str(other_src)) != key
-    assert native.build_key(flags=native.CXXFLAGS + ("-DX",)) != key
-    assert native.build_key(cpu="x86_64:sse2") != key
-    assert key in native._so_path(key)
+    assert native_build.build_key(str(other_src)) != key
+    assert native_build.build_key(
+        native._SRC, native_build.CXXFLAGS + ("-DX",)) != key
+    assert native._LIB.key(cpu="x86_64:sse2") != key
+    assert key in native._LIB.path(key)
 
 
 def test_stale_binary_from_another_build_is_not_loaded(tmp_path,
@@ -34,16 +37,17 @@ def test_stale_binary_from_another_build_is_not_loaded(tmp_path,
     loaded: this host builds and loads its own keyed binary."""
     monkeypatch.setenv("DEEPFLOW_TPU_NATIVE_DIR", str(tmp_path))
     stale = [tmp_path / "_native_decoder.so",
-             tmp_path / f"_native_decoder-{native.build_key(cpu='arm')}.so",
-             tmp_path / "_native_decoder-{}.so".format(native.build_key(
-                 flags=("-O0",)))]
+             tmp_path / f"_native_decoder-{native._LIB.key(cpu='arm')}.so",
+             tmp_path / "_native_decoder-{}.so".format(
+                 native_build.build_key(native._SRC, flags=("-O0",)))]
     for p in stale:
         p.write_bytes(b"not an ELF")
-    monkeypatch.setattr(native, "_SO", native._so_path())
-    monkeypatch.setattr(native, "_lib", None)
-    monkeypatch.setattr(native, "_build_error", None)
+    fresh = native_build.NativeLib(native._SRC, "_native_decoder",
+                                   native._bind, libs=("-lpthread",))
+    monkeypatch.setattr(native, "_LIB", fresh)
     assert native.available(), native.build_error()
-    assert native._SO not in {str(p) for p in stale}
+    assert fresh.path() not in {str(p) for p in stale}
+    assert fresh.path().startswith(str(tmp_path))
     assert all(p.read_bytes() == b"not an ELF" for p in stale)
     got, bad = native.decode_l4_payload(
         pack_pb_records(SyntheticAgent().l4_batch(5)[1]))

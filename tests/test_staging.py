@@ -437,6 +437,55 @@ def test_dict_staged_drain_conservation():
     assert c["dict_epoch_drops"] == 0      # no rollback, no stale drops
 
 
+@pytest.mark.parametrize("native", [True, False])
+def test_dict_stager_counts_resolved_rows(native, monkeypatch, caplog):
+    """Every staged row is counted under the resolver that packed it:
+    the native key table wherever it builds; with it unavailable the
+    pure-Python one runs, logged once however many packers a
+    device-state restore builds. A restore frees the old native
+    table."""
+    import gc
+    import logging
+    import weakref
+
+    from deepflow_tpu.batch.staging import DictWireStager
+    from deepflow_tpu.models import flow_dict, flow_dict_native
+
+    if native and not flow_dict_native.available():
+        pytest.skip(f"no native flow dict: {flow_dict_native.build_error()}")
+    if not native:
+        monkeypatch.setattr(flow_dict_native, "available", lambda: False)
+    monkeypatch.setattr(flow_dict, "_python_resolution_logged", False)
+    caplog.set_level(logging.WARNING, logger=flow_dict.__name__)
+    st = DictWireStager(
+        512, group_batches=2,
+        packer_factory=lambda: flow_dict.FlowDictPacker(
+            capacity=4096, hits_batch=512))
+    rng, pool = _pool(seed=21, n=1024, hi=1 << 12)
+    sent = 0
+    for i, c in enumerate(_chunks(rng, pool, n_chunks=6, rows=700)):
+        if i == 3:
+            old = weakref.ref(st._packer._keys)
+            st.reset_packer()
+            gc.collect()
+            assert old() is None
+            assert len(st._packer._keys) == 0
+        st.put(c)
+        sent += 700
+    st.flush()
+    c = st.counters()
+    assert c["staged_rows"] == sent
+    resolved = ("dict_resolve_native_rows" if native
+                else "dict_resolve_python_rows")
+    assert c[resolved] == sent
+    assert c["dict_resolve_native_rows"] + \
+        c["dict_resolve_python_rows"] == sent
+    assert st._packer.resolve_native is native
+    logged = [r for r in caplog.records
+              if "resolving dict-wire keys in Python" in r.getMessage()]
+    assert len(logged) == (0 if native else 1)
+
+
 def test_zero_copy_degraded_absorbs_staged_dict():
     """Device loss with staged dict groups in flight: rollback swaps
     the packer (epoch bump), groups staged against the DEAD epoch are
