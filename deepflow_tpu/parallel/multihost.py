@@ -418,9 +418,9 @@ class _HostLane:
 
     __slots__ = ("idx", "pod", "status", "gen", "outbox", "del_seen",
                  "last_local", "marker_rows", "base_sent",
-                 "base_delivered", "base_host", "base_lost", "gmerged",
-                 "glost", "drop_rows", "rejoin_lost", "stop_ev",
-                 "handle", "close_lock")
+                 "base_delivered", "base_host", "base_lost", "base_h2d",
+                 "gmerged", "glost", "drop_rows", "rejoin_lost",
+                 "stop_ev", "handle", "close_lock")
 
     def __init__(self, idx: int, pod: PodFlowSuite) -> None:
         self.idx = idx
@@ -435,6 +435,7 @@ class _HostLane:
         self.base_delivered = 0
         self.base_host = 0
         self.base_lost = 0
+        self.base_h2d = 0
         self.gmerged = 0           # rows globally merged (pod-wide delivered)
         self.glost = 0             # taken-for-merge rows the merge lost
         self.drop_rows = 0         # routed to a LOST host: sent AND lost
@@ -990,6 +991,7 @@ class HostPodCoordinator:
             ln.base_delivered += fin["pod_rows_delivered"]
             ln.base_host += fin["pod_rows_host"]
             ln.base_lost += fin["pod_rows_lost"]
+            ln.base_h2d += ln.pod.h2d_bytes
             ln.rejoin_lost += fin["pod_rows_pending"]
             ln.gen += 1
             ln.del_seen = 0
@@ -1089,6 +1091,12 @@ class HostPodCoordinator:
                 out.append(row)
             base += ln.pod.n_shards
         return out
+
+    @property
+    def h2d_bytes(self) -> int:
+        """Host-to-device bytes every host's shard workers put, dead
+        incarnations included."""
+        return sum(ln.base_h2d + ln.pod.h2d_bytes for ln in self._lanes)
 
     def counters(self) -> dict:
         """The pod-WIDE ledger, one consistent snapshot: every term of

@@ -165,6 +165,7 @@ class _Shard:
         self.marker_rows = 0               # epoch membership at marker post
         self.batches_since_snapshot = 0
         self._host = None                  # _HostSketch when degraded
+        self.h2d_bytes = 0                 # slice bytes put (worker only)
 
 
 class PodFlowSuite:
@@ -206,13 +207,14 @@ class PodFlowSuite:
         self.snapshot_batches = max(1, int(snapshot_batches))
         self.auto_rejoin = bool(auto_rejoin)
         self.name = name
-        # the POD-MERGED bus serving/ subscribes to, plus one bus per
-        # shard for rollback snapshots + rejoin-by-snapshot. One
-        # directory, distinct names — snapbus filenames never collide.
+        # the POD-MERGED bus serving/ subscribes to (on disk when a
+        # snapshot_dir is given), plus one in-process bus per shard for
+        # rollback snapshots + rejoin-by-snapshot. A shard snapshot is
+        # read back only by this incarnation (its `run` tag), so a
+        # restart has no use for it on disk.
         self.bus = SnapshotBus(snapshot_dir, name=name)
         self._shards: List[_Shard] = [
-            _Shard(i, devices[i],
-                   SnapshotBus(snapshot_dir, name=f"{name}-shard{i}"),
+            _Shard(i, devices[i], SnapshotBus(None, name=f"{name}-shard{i}"),
                    queue_batches)
             for i in range(self.n_shards)]
         # resume the epoch counter past a prior run's disk snapshots,
@@ -222,9 +224,8 @@ class PodFlowSuite:
         # way)
         last = self.bus.latest_step()
         self.epoch = 0 if last is None else last + 1
-        # per-incarnation nonce on shard snapshots: with a disk-backed
-        # bus, latest() falls back to a PRIOR process's snapshots —
-        # restoring one would risk double-merging rows the dead run
+        # per-incarnation nonce on shard snapshots: restoring another
+        # incarnation's snapshot would risk double-merging rows it
         # already delivered (its gen ledger died with it), so a restart
         # loses at most the open epoch's per-shard accumulation instead
         self._run_id = uuid.uuid4().hex
@@ -270,21 +271,24 @@ class PodFlowSuite:
         # mask arithmetic mirrors sharded.local_update_lanes: global
         # position = arange(b) + shard_offset, valid iff < n. Same
         # values, same flow_suite.update — per-shard state equals the
-        # mesh lane's per-device partial bit-for-bit.
-        def _upd_lanes(s, p, off, n):
+        # mesh lane's per-device partial bit-for-bit. Named functions:
+        # the XLA programs (and a device trace) carry
+        # jit_sketch_update_pod_<wire>, as the single-chip lane's do.
+        def sketch_update_pod_lanes(s, p, off, n):
             lanes = {"ip_src": p[0], "ip_dst": p[1],
                      "ports": p[2], "proto_pkts": p[3]}
             mask = (jnp.arange(p.shape[1], dtype=jnp.uint32) + off) < n
             return flow_suite.update(s, flow_suite.unpack_lanes(lanes),
                                      mask, cfg_)
 
-        self._upd_lanes = jax.jit(_upd_lanes, donate_argnums=0)
+        self._upd_lanes = jax.jit(sketch_update_pod_lanes,
+                                  donate_argnums=0)
         if wire == "dict":
             from deepflow_tpu.models import flow_dict as _fd
             self._fd = _fd
             self._dict_capacity = int(dict_capacity)
 
-            def _upd_news(s, table, p, n, shard_idx):
+            def sketch_update_pod_news(s, table, p, n, shard_idx):
                 rows = jnp.arange(p.shape[1], dtype=jnp.uint32)
                 count = (rows < n) & (rows % jnp.uint32(nd) == shard_idx)
                 st, ts = _fd.update_news(
@@ -292,7 +296,7 @@ class PodFlowSuite:
                     count_mask=count)
                 return st, ts.table
 
-            def _upd_hits(s, table, p, off_pairs, n):
+            def sketch_update_pod_hits(s, table, p, off_pairs, n):
                 hp = p.shape[1]
                 pos_a = jnp.arange(hp, dtype=jnp.uint32) + off_pairs
                 gmask = jnp.concatenate(
@@ -301,8 +305,10 @@ class PodFlowSuite:
                     s, _fd.FlowDictState(table=table), p, n, cfg_,
                     mask=gmask)
 
-            self._upd_news = jax.jit(_upd_news, donate_argnums=(0, 1))
-            self._upd_hits = jax.jit(_upd_hits, donate_argnums=0)
+            self._upd_news = jax.jit(sketch_update_pod_news,
+                                     donate_argnums=(0, 1))
+            self._upd_hits = jax.jit(sketch_update_pod_hits,
+                                     donate_argnums=0)
         self._merge_progs: Dict[int, Any] = {}
         for sh in self._shards:
             self._init_shard_state(sh)
@@ -507,21 +513,28 @@ class PodFlowSuite:
             self._faults.maybe_raise(FAULT_SHARD_DEVICE_ERROR,
                                      key=f"shard{sh.idx}:update")
         kind = item[0]
-        if kind == "lanes":
-            _, plane, off, n, _ = item
-            p = jax.device_put(np.ascontiguousarray(plane), sh.device)
-            sh.state = self._upd_lanes(sh.state, p, jnp.uint32(off),
-                                       jnp.uint32(n))
-        elif kind == "news":
-            _, plane, n, _ = item
-            p = jax.device_put(np.ascontiguousarray(plane), sh.device)
-            sh.state, sh.dtable = self._upd_news(
-                sh.state, sh.dtable, p, jnp.uint32(n), jnp.uint32(sh.idx))
-        else:  # hits
-            _, plane, off, n, _ = item
-            p = jax.device_put(np.ascontiguousarray(plane), sh.device)
-            sh.state = self._upd_hits(sh.state, sh.dtable, p,
-                                      jnp.uint32(off), jnp.uint32(n))
+        # the slice copy, its transfer and the asynchronous update call;
+        # the update's device time is the device trace's
+        with self._tracer.span("pod.update", stream=kind, rows=rows):
+            plane = np.ascontiguousarray(item[1])
+            p = jax.device_put(plane, sh.device)
+            sh.h2d_bytes += plane.nbytes
+            # numpy scalars ride the call to the shard's device; a jnp
+            # scalar would be an eager array on the default device,
+            # then a copy across chips, twice per call
+            if kind == "lanes":
+                _, _, off, n, _ = item
+                sh.state = self._upd_lanes(sh.state, p, np.uint32(off),
+                                           np.uint32(n))
+            elif kind == "news":
+                _, _, n, _ = item
+                sh.state, sh.dtable = self._upd_news(
+                    sh.state, sh.dtable, p, np.uint32(n),
+                    np.uint32(sh.idx))
+            else:  # hits
+                _, _, off, n, _ = item
+                sh.state = self._upd_hits(sh.state, sh.dtable, p,
+                                          np.uint32(off), np.uint32(n))
         with self._ledger:
             sh.active_rows = 0
             if sh.status == LOST:
@@ -542,8 +555,7 @@ class PodFlowSuite:
         at most ``snapshot_batches`` batches of this shard's slice."""
         sh.bus.publish(sh.state, step=self.epoch,
                        tags={"epoch": self.epoch, "rows": sh.rows_epoch,
-                             "gen": sh.gen, "run": self._run_id},
-                       to_disk=sh.bus.directory is not None)
+                             "gen": sh.gen, "run": self._run_id})
         with self._ledger:
             sh.snap_rows = sh.rows_epoch
         sh.batches_since_snapshot = 0
@@ -680,8 +692,9 @@ class PodFlowSuite:
         rows = 0
         if not degraded or sh.rows_epoch > 0:
             try:
-                leaves = tuple(np.asarray(x) for x in jax.device_get(
-                    jax.tree_util.tree_leaves(sh.state)))
+                with self._tracer.span("pod.contribute"):
+                    leaves = tuple(np.asarray(x) for x in jax.device_get(
+                        jax.tree_util.tree_leaves(sh.state)))
             except RuntimeError:
                 # device lost at the epoch sync: the same ladder as a
                 # failed update — roll back from the gen-matching
@@ -812,35 +825,40 @@ class PodFlowSuite:
                         if sh.status in (ACTIVE, DEGRADED)]
             lost_now = [sh.idx for sh in self._shards
                         if sh.status == LOST]
-        with self._ledger:
-            # every marker posts inside ONE ledger section, atomic vs
-            # put_lanes/put_wire's book+enqueue: a batch is wholly
-            # before or wholly after this epoch on EVERY shard (never
-            # split across epochs under the audit shadow), and each
-            # marker_rows membership snapshot — rows in the shard's
-            # pipeline at its marker — is exact. Rows arriving during
-            # the deadline wait belong to the NEXT epoch and never
-            # inflate this epoch's exclusion count.
-            for sh in self._shards:
-                if sh.idx in expected:
-                    sh.marker_rows = (sh.qrows + sh.active_rows
-                                      + sh.rows_epoch
-                                      + sh.contrib_inflight)
-                    try:
-                        sh.q.put_nowait(("epoch", ep))  # lint: disable=emit-under-lock
-                    except _queue.Full:
-                        # a full queue is already a deep straggler: the
-                        # shard reads as missed and merges late
-                        pass
-        deadline = time.monotonic() + (self.merge_deadline_s
-                                       if deadline_s is None
-                                       else float(deadline_s))
-        while time.monotonic() < deadline:
+        # the close's wait for its slowest shard: markers posted, then
+        # every expected contribution, or the deadline
+        with self._tracer.span("pod.merge_wait"):
             with self._ledger:
-                got = {c.shard for c in self._pending if c.epoch == ep}
-            if set(expected) <= got:
-                break
-            time.sleep(0.002)
+                # every marker posts inside ONE ledger section, atomic
+                # vs put_lanes/put_wire's book+enqueue: a batch is
+                # wholly before or wholly after this epoch on EVERY
+                # shard (never split across epochs under the audit
+                # shadow), and each marker_rows membership snapshot —
+                # rows in the shard's pipeline at its marker — is
+                # exact. Rows arriving during the deadline wait belong
+                # to the NEXT epoch and never inflate this epoch's
+                # exclusion count.
+                for sh in self._shards:
+                    if sh.idx in expected:
+                        sh.marker_rows = (sh.qrows + sh.active_rows
+                                          + sh.rows_epoch
+                                          + sh.contrib_inflight)
+                        try:
+                            sh.q.put_nowait(("epoch", ep))  # lint: disable=emit-under-lock
+                        except _queue.Full:
+                            # a full queue is already a deep straggler:
+                            # the shard reads as missed and merges late
+                            pass
+            deadline = time.monotonic() + (self.merge_deadline_s
+                                           if deadline_s is None
+                                           else float(deadline_s))
+            while time.monotonic() < deadline:
+                with self._ledger:
+                    got = {c.shard for c in self._pending
+                           if c.epoch == ep}
+                if set(expected) <= got:
+                    break
+                time.sleep(0.002)
         with self._ledger:
             take, self._pending = self._pending, []
             # the lossy flag is snapped HERE, at the contribution take,
@@ -884,9 +902,10 @@ class PodFlowSuite:
         merged_rows = 0
         if device_contribs:
             try:
-                out, merged_rows = self._merge_epoch(
-                    device_contribs, ep, now=now, missed=missed,
-                    degraded=degraded_now, lost=lost_now, lossy=lossy)
+                with self._tracer.span("pod.merge"):
+                    out, merged_rows = self._merge_epoch(
+                        device_contribs, ep, now=now, missed=missed,
+                        degraded=degraded_now, lost=lost_now, lossy=lossy)
             except Exception:
                 # the merge path itself died (device loss during the
                 # stacked program or the publish device_get — the very
@@ -1150,6 +1169,12 @@ class PodFlowSuite:
         n += sum(c.rows for c in self._pending
                  if c.leaves is not None)
         return n + self._merge_inflight
+
+    @property
+    def h2d_bytes(self) -> int:
+        """Host-to-device bytes of the batch slices the shard workers
+        put, summed over the shards (each counter has one writer)."""
+        return sum(sh.h2d_bytes for sh in self._shards)
 
     def shard_status(self) -> List[dict]:
         with self._ledger:

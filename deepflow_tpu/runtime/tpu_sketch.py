@@ -388,7 +388,7 @@ class TpuSketchExporter(QueueWorkerExporter):
         # `_gauge_every`-th batch.
         self._tracer = default_tracer()
         self._warm: set = set()
-        self.h2d_bytes = 0
+        self._h2d_bytes = 0
         self._gauge_every = 16
         self._batches_traced = 0
         self._gauge_due = False
@@ -653,10 +653,11 @@ class TpuSketchExporter(QueueWorkerExporter):
 
     def _pod_submit_locked(self, tb: TensorBatch) -> None:
         """One TensorBatch onto the pod lane: host-pack the 4-plane
-        lane matrix (a fresh buffer — the pod keeps views) and fan it
-        across the shard queues; the TensorBatch recycles immediately."""
-        lanes = flow_suite.pack_lanes(tb.columns)
-        plane = np.stack([lanes[k] for k in flow_suite.SKETCH_LANE_NAMES])
+        lane matrix straight into a fresh buffer (the pod keeps views)
+        and fan it across the shard queues; the TensorBatch recycles
+        immediately."""
+        plane = np.empty((4, len(tb.columns["ip_src"])), np.uint32)
+        flow_suite.pack_lanes_into(tb.columns, plane)
         self._pod.put_lanes(plane, int(tb.valid))
         self.batcher.recycle(tb)
 
@@ -678,9 +679,17 @@ class TpuSketchExporter(QueueWorkerExporter):
         totals (scraped beside rows_in): transfers-vs-batches is the
         coalescing regression signal — a slide back toward per-plane
         puts shows up as h2d_transfers outgrowing batches."""
-        self.h2d_bytes += host_array.nbytes
+        self._h2d_bytes += host_array.nbytes
         self.h2d_transfers += 1
         return self._jnp.asarray(host_array)
+
+    @property
+    def h2d_bytes(self) -> int:
+        """Host-to-device bytes of the lane's batches: `_to_device`'s
+        count, or in pod mode what the shard workers put."""
+        if self._pod is not None:
+            return self._pod.h2d_bytes
+        return self._h2d_bytes
 
     def _timed_update(self, key: str, fn, *args):
         """One jitted update call. The first call per program is its

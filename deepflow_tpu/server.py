@@ -19,6 +19,8 @@ Config (all keys optional):
       debug_port: 30035
       throttle_per_s: 50000
       tpu_sketch_window_s: 1.0
+      tpu_sketch_pod_shards: 0  # >= 2: an epoch-merged pod, a shard per chip
+      pod_merge_deadline_s: 5.0 # a shard later than this misses the merge
       app_red_window_s: 1.0
       trace_enabled: true      # the flight recorder (runtime/tracing.py)
     querier:
@@ -105,6 +107,8 @@ class Server:
             throttle_per_s=ing_cfg.get("throttle_per_s", 50_000),
             store_max_bytes=ing_cfg.get("store_max_bytes", 100 << 30),
             tpu_sketch_window_s=ing_cfg.get("tpu_sketch_window_s"),
+            tpu_sketch_pod_shards=ing_cfg.get("tpu_sketch_pod_shards", 0),
+            pod_merge_deadline_s=ing_cfg.get("pod_merge_deadline_s", 5.0),
             app_red_window_s=ing_cfg.get("app_red_window_s"),
             trace_enabled=ing_cfg.get("trace_enabled", True),
         ))
